@@ -1,8 +1,10 @@
-"""Two-process ``jax.distributed`` smoke for the 2-D mesh scale-out path.
+"""Two-process ``jax.distributed`` smoke for the 2-D mesh scale-out path:
+a CPU rehearsal.
 
 Run with no arguments, the driver re-executes itself as two coordinated
 worker processes (``--process-id 0|1``), each given two forced host
-devices, and checks the multi-process story end to end as far as the CPU
+devices on the CPU backend (``JAX_PLATFORMS=cpu`` in their environment,
+so on a TPU host neither child claims the chips), and checks the multi-process story end to end as far as the CPU
 backend permits:
 
   1. ``jax.distributed.initialize`` handshake: both workers join one
@@ -20,8 +22,8 @@ backend permits:
      silently pretend to the global shape.
 
 Cross-process collectives themselves are NOT exercised: XLA:CPU raises
-``Multiprocess computations aren't implemented on the CPU backend``
-(verified empirically on jax 0.4.x), so a CPU CI can validate the
+``Multiprocess computations aren't implemented on the CPU backend``,
+so a CPU CI can validate the
 handshake, the world assembly, and the per-host shard math, while the
 collective seam exchange across hosts needs a real TPU/GPU runner.
 Exits 0 on success, 1 with the failing worker's log on any mismatch.
@@ -93,7 +95,7 @@ def main(argv=None) -> int:
     if a.process_id is not None:
         worker(a.process_id)
         return 0
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={LOCAL_DEVICES}"
     )

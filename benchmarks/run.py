@@ -41,6 +41,10 @@ def main(argv=None) -> None:
                         f"JSON to {BENCH_FLEET_JSON}")
     args = p.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     # Each benchmark imports INSIDE its own try block: a single broken
     # module (or a missing optional dep) must fail that one benchmark
     # loudly -- counted in `failures`, nonzero exit -- instead of an

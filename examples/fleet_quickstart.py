@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import MeshSpec, sobel_grid
 from repro.core import applications as apps
 from repro.runtime import FaultInjector, RetryPolicy
@@ -26,6 +27,7 @@ from repro.serve import FleetFrontend, QuarantinedError, StreamingFrontend
 
 
 def main():
+    enable_compile_cache()
     print("=== Pixie fleet quickstart: multi-tenant overlay serving ===\n")
     rng = np.random.default_rng(0)
     # Device placement is a structured MeshSpec: `app` shards tenants,

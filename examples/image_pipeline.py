@@ -15,11 +15,13 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.data import PixiePreprocessor, patch_embed_stub, synthetic_images
 
 
 def main():
+    enable_compile_cache()
     cfg = get_arch("paligemma-3b")
     pre = PixiePreprocessor(filters=("sobel_mag", "gauss3", "sharpen", "laplace"))
     print(f"overlay grid: {pre.grid}")

@@ -19,6 +19,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Pixie, map_app
 from repro.core import applications as apps
 from repro.core.grid import custom
@@ -41,6 +42,7 @@ def chain_grid():
 
 
 def main():
+    enable_compile_cache()
     print("=== Pixie pipeline quickstart: device-resident chains ===\n")
     rng = np.random.default_rng(0)
     grid = chain_grid()

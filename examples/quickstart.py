@@ -14,6 +14,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Pixie, SOBEL_SOURCE, map_app, sobel_grid, synthesize, for_dfg
 from repro.core import applications as apps
 from repro.core.grid import rectangular
@@ -21,6 +22,7 @@ from repro.core.place import level_demand
 
 
 def main():
+    enable_compile_cache()
     print("=== Pixie quickstart: Sobel on the 45-PE VCGRA (paper Sec. IV) ===\n")
 
     # 1. the application, synthesized from its textual description

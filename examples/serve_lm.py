@@ -13,12 +13,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, reduced
 from repro.models import LM
 from repro.serve import ServeConfig, ServeEngine, SlotServer
 
 
 def main():
+    enable_compile_cache()
     cfg = reduced(ARCHS["gemma-2b"])
     lm = LM(cfg, remat="none", chunk_q=64, loss_chunk=64)
     params = lm.init(jax.random.PRNGKey(0))

@@ -14,6 +14,7 @@ import tempfile
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.data import TokenPipeline
 from repro.models import LM
@@ -22,6 +23,7 @@ from repro.train import LoopConfig, train_loop
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=4)

@@ -70,8 +70,6 @@ def compile_and_census(fn: Callable, *args) -> Dict[str, float]:
     compiled = lowered.compile()
     census = hlo_op_census(compiled.as_text())
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     census["flops"] = float(cost.get("flops", 0.0))
     census["bytes"] = float(cost.get("bytes accessed", 0.0))
     return census

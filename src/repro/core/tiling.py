@@ -12,26 +12,30 @@ already-valid config whose outputs are discarded.
 
 from __future__ import annotations
 
-import math
 from typing import Dict, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 
 #: Default on-chip working-set budget for the pixel-tiled fused executors
-#: (bytes).  Half of a TPU core's ~16 MiB VMEM; the resident working set
-#: -- BOTH in-flight DMA slabs of the double buffer plus the ``(T+1)``-row
-#: tap bank, the memory-VC channels and the widest PE level, all
-#: ``[_, tile_rows(+2*radius), W]``-shaped -- must fit in it (the other
-#: half is headroom for the settings banks and compiler temporaries).
+#: (bytes): what :func:`slab_rows_per_budget` lets the kernel's own VMEM
+#: scratch grow to.  The TPU compiler's scoped-VMEM limit for one kernel
+#: (16 MiB on a v5e) must also hold the pipelined output blocks and the
+#: compiler's temporaries for the PE datapath, which this model does not
+#: count; 8 MiB leaves that room at 1080p and 4K canvas widths (checked
+#: by compiling the megakernels for a described v5e, see
+#: tests/test_tpu_compile.py).
 DEFAULT_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
-#: Lane width of the TPU vector unit: the compiled megakernel needs its
-#: flattened pixel block (``tile_rows * W``) to be a multiple of this.
-#: Re-exported by ``kernels/vcgra/vcgra_kernel.py``; defined here so the
-#: tile-height resolver (:func:`resolve_tile_rows`) and the kernel agree
-#: on one constant.
+#: Lane width of the TPU vector unit: the compiled megakernels pad the
+#: canvas width to a multiple of this (the lane axis of every block).
+#: Re-exported by ``kernels/vcgra/vcgra_kernel.py``.
 LANE = 128
+
+#: Sublane count of a 32-bit TPU vreg tile: compiled tile heights are
+#: whole multiples of this (or one tile covering the frame), the
+#: second-minor block rule of the TPU compiler.
+SUBLANE = 8
 
 #: Sentinel ``OverlayPlan.tile_rows`` value: resolve the row-tile height
 #: from the VMEM budget heuristic at trace time (shapes are static under
@@ -66,40 +70,38 @@ def slab_rows_per_budget(
     num_inputs: int,
     max_level_width: int,
     itemsize: int,
+    num_outputs: int = 1,
     budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
 ) -> int:
     """How many *output* rows of a fused row-tile fit the VMEM budget.
 
-    The fused megakernel's resident working set per kernel instance is
-    the tap bank (``(2r+1)^2 + 1`` producer rows), the memory-VC channel
-    matrix (``num_inputs`` rows) and the widest PE level
-    (``max_level_width`` rows), each ``tile_rows * W`` elements, plus
-    BOTH ``(tile_rows + 2*radius) * W`` slabs of the in-kernel DMA double
+    The fused megakernel's VMEM per kernel instance is the tap bank
+    (``(2r+1)^2`` rows of ``tile_rows * W`` elements), BOTH
+    ``(tile_rows + 2*radius) * W`` slabs of the in-kernel DMA double
     buffer (tile t computes out of one while tile t+1 streams HBM->VMEM
-    into the other).  Solving ``bytes_per_output_row * tile_rows +
-    halo_bytes <= budget`` for ``tile_rows`` (the constant ``2 * 2*radius
-    * W`` double-buffer halo comes off the budget up front, so the pick
-    never exceeds it) gives the heuristic.
+    into the other) and the double-buffered ``num_outputs * tile_rows *
+    W`` output block, plus a fixed set of block-sized buffers the PE
+    datapath walks (``num_inputs`` channels and ``2 * max_level_width``
+    level values, each one ``SUBLANE x 512`` block).  Solving
+    ``bytes_per_output_row * tile_rows + fixed_bytes <= budget`` for
+    ``tile_rows`` (the fixed part -- the slabs' ``2 * 2*radius * W`` halo
+    rows and the datapath blocks -- comes off the budget up front, so the
+    pick never exceeds it) gives the heuristic.
     """
-    taps = (2 * radius + 1) ** 2 + 1
+    taps = (2 * radius + 1) ** 2
     width = max(W, 1)
-    per_row = (taps + num_inputs + max_level_width + 2) * width * itemsize
-    budget = int(budget_bytes) - 2 * (2 * radius) * width * itemsize
-    return max(1, budget // per_row)
+    per_row = (taps + 2 + 2 * num_outputs) * width * itemsize
+    fixed = (2 * (2 * radius) * width
+             + (num_inputs + 2 * max_level_width) * SUBLANE * 512) * itemsize
+    return max(1, (int(budget_bytes) - fixed) // per_row)
 
 
-def lane_aligned_tile_rows(tile_rows: int, W: int, lane: int = LANE) -> int:
-    """Round a tile height DOWN to the largest multiple of
-    ``lane / gcd(W, lane)`` that is <= ``tile_rows`` (and at least that
-    granule), which guarantees ``(tile_rows * W) % lane == 0`` -- the
-    pixel-block layout constraint of the compiled megakernel -- while
-    only ever shrinking the working set.  THE one definition of the
-    rounding, shared by the AUTO-tile heuristic (:func:`resolve_tile_rows`
-    with ``lane_align=``) and any caller that wants to pre-check an
-    explicit tile height."""
-    g = lane // math.gcd(max(int(W), 1), lane)
+def aligned_tile_rows(tile_rows: int, align: int = SUBLANE) -> int:
+    """Round a tile height DOWN to a multiple of ``align`` (at least
+    ``align``) -- the compiled megakernels' whole-sublane-tile rule --
+    while only ever shrinking the working set."""
     tr = int(tile_rows)
-    return max(g, tr - tr % g)
+    return max(align, tr - tr % align)
 
 
 def resolve_tile_rows(
@@ -109,7 +111,7 @@ def resolve_tile_rows(
     radius: int,
     grid,
     budget_bytes: int = DEFAULT_VMEM_BUDGET_BYTES,
-    lane_align: Optional[int] = None,
+    align: Optional[int] = None,
 ) -> int:
     """Resolve a plan's ``tile_rows`` axis against one frame shape.
 
@@ -119,14 +121,14 @@ def resolve_tile_rows(
     ``tile_rows >= H`` degenerates to the untiled single-slab layout, so
     small frames pay no tiling machinery under the auto default.
 
-    ``lane_align`` (the compiled megakernel passes its LANE width; the
-    XLA twin and interpret mode pass None -- no layout constraint there)
-    rounds an AUTO pick that actually tiles down to a lane-aligned tile
-    height via :func:`lane_aligned_tile_rows`, so the heuristic, the XLA
-    tiled twin and the compiled DMA path all resolve through this ONE
-    definition and the kernel's loud lane-align assert fires with the
-    already-rounded value.  Explicit int tile heights are the caller's
-    choice and are never silently rewritten.
+    ``align`` (the compiled megakernels pass :data:`SUBLANE`; the XLA
+    twin and interpret mode pass None -- no layout constraint there)
+    rounds an AUTO pick that actually tiles down to whole sublane tiles
+    via :func:`aligned_tile_rows`, so the heuristic, the XLA tiled twin
+    and the compiled DMA path all resolve through this ONE definition and
+    the kernel's loud alignment assert fires with the already-rounded
+    value.  Explicit int tile heights are the caller's choice and are
+    never silently rewritten.
     """
     if tile_rows is None:
         return max(int(H), 1)
@@ -136,11 +138,12 @@ def resolve_tile_rows(
             num_inputs=grid.num_inputs,
             max_level_width=max(grid.pes_per_level),
             itemsize=jnp.dtype(grid.dtype).itemsize,
+            num_outputs=grid.num_outputs,
             budget_bytes=budget_bytes,
         )
         picked = max(1, min(picked, int(H)))
-        if lane_align and picked < int(H):
-            picked = lane_aligned_tile_rows(picked, W, lane_align)
+        if align and picked < int(H):
+            picked = min(aligned_tile_rows(picked, align), int(H))
         return picked
     return max(1, min(int(tile_rows), int(H)))
 

@@ -22,7 +22,6 @@ from repro.core.grid import GridSpec
 from repro.core.interpreter import apply_ingest, form_tap_bank, pack_inputs
 from repro.core.plan import OverlayPlan, register_executor
 from repro.kernels.vcgra.vcgra_kernel import (
-    LANE,
     _pack_settings,
     default_interpret,
     vcgra_batched,
@@ -168,27 +167,24 @@ def pallas_pipeline_stage_fn(grid: GridSpec, tile_rows=None, interpret=None):
     return stage_fn
 
 
-def _batched_pallas_fn(grid: GridSpec, block_n: int = LANE, interpret=None):
+def _batched_pallas_fn(grid: GridSpec, block_n: Optional[int] = None,
+                       interpret=None):
     """Unjitted batched (pre-packed channels) kernel executor -- the
     Pallas twin of ``interpreter.batched_overlay_step``:
     ``fn(stacked_configs, xs) -> ys`` with ``xs: [N, num_inputs, B]``.
-    The pixel axis is padded to a ``block_n`` multiple inside the
-    function and sliced back, so callers keep the XLA path's contract."""
+    The kernel pads the pixel axis to whole blocks and slices it back, so
+    callers keep the XLA path's contract."""
 
     def fn(stacked_configs, xs):
         settings = pack_settings_batched(grid, stacked_configs)
-        b = xs.shape[-1]
-        rem = (-b) % block_n
-        if rem:
-            xs = jnp.pad(xs, ((0, 0), (0, 0), (0, rem)))
-        ys = vcgra_batched(grid, settings, xs, block_n=block_n,
-                           interpret=interpret)
-        return ys[:, :, :b]
+        return vcgra_batched(grid, settings, xs, block_n=block_n,
+                             interpret=interpret)
 
     return fn
 
 
-def make_batched_pallas_fn(grid: GridSpec, block_n: int = LANE, interpret=None):
+def make_batched_pallas_fn(grid: GridSpec, block_n: Optional[int] = None,
+                           interpret=None):
     """Jit-once standalone form of :func:`_batched_pallas_fn`."""
     return jax.jit(_batched_pallas_fn(grid, block_n, interpret))
 

@@ -41,10 +41,13 @@ from repro.core.ingest import tap_offsets
 from repro.core.ops import Op
 from repro.core.specialize import _live_slots
 
-# LANE is defined in core/tiling.py (the tile-height resolver and the
-# kernel must agree on one constant); re-exported here, its historical
-# home, for the callers that import it from the kernel package.
-from repro.core.tiling import LANE, num_row_tiles, resolve_tile_rows  # noqa: F401
+# LANE and SUBLANE are defined in core/tiling.py (the tile-height
+# resolver and the kernel must agree on them); LANE is re-exported here,
+# its historical home, for the callers that import it from the kernel
+# package.
+from repro.core.tiling import (  # noqa: F401
+    LANE, SUBLANE, num_row_tiles, resolve_tile_rows,
+)
 
 
 def default_interpret() -> bool:
@@ -110,56 +113,90 @@ def vcgra_specialized(
     )(x)
 
 
-# -- conventional kernel ---------------------------------------------------------
+# -- conventional kernels --------------------------------------------------------
+#
+# Every conventional body runs the PE-level pipeline through VMEM refs
+# with a leading value axis: a PE's routing select is a dynamic index on
+# that untiled axis (an address offset, the VC mux analogue), and its
+# opcode is an SMEM scalar.  Values are ``[rows, lanes]`` pixel blocks,
+# never flattened, so every load, store and elementwise op keeps the
+# (sublane, lane) tiling the TPU compiler requires.  Settings arrays are
+# scalar-prefetched FLAT (SMEM pads the trailing dimensions of a
+# multi-dimensional operand to whole tiles, which overflows SMEM for
+# stage-stacked banks) and read through :class:`_Smem`.
 
 
-def _level_pipeline(grid: GridSpec, idx: Tuple, op_ref, sel_ref,
-                    x: jnp.ndarray) -> jnp.ndarray:
-    """The conventional PE-level pipeline, shared by the single-app and
-    batched kernel bodies.
+class _Smem:
+    """Row-major multi-index view of a flattened SMEM operand."""
 
-    ``idx`` prefixes every SMEM read: ``()`` for per-app settings refs
-    (``op_ref [L, max_w]``), ``(i,)`` for batched banks with a leading app
-    axis (``op_ref [N, L, max_w]``).  ``x``: [num_inputs, pixels] ->
-    [last_level_width, pixels].  Dense settings are padded to max_w but
-    only the grid's true per-level width is ever read, so pad slots cost
-    nothing.
+    def __init__(self, ref, shape: Tuple[int, ...]):
+        self._ref = ref
+        strides, acc = [], 1
+        for d in reversed(shape):
+            strides.append(acc)
+            acc *= int(d)
+        self._strides = tuple(reversed(strides))
+
+    def __getitem__(self, idx):
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        off = 0
+        for i, stride in zip(idx, self._strides):
+            off = off + i * stride
+        return self._ref[off]
+
+
+def _smem_operands(*arrays):
+    """Flatten scalar-prefetch operands; returns ``(flat, shapes)``.  Const
+    values ride as 32-bit words (SMEM holds 32-bit scalars; 16-bit grid
+    values widen and narrow back exactly)."""
+    flat, shapes = [], []
+    for a in arrays:
+        a = jnp.asarray(a)
+        if a.dtype.itemsize != 4:
+            a = a.astype(jnp.int32 if jnp.issubdtype(a.dtype, jnp.integer)
+                         else jnp.float32)
+        flat.append(a.reshape(-1))
+        shapes.append(tuple(a.shape))
+    return flat, tuple(shapes)
+
+
+def _clamp(idx, size: int):
+    """Clamp a runtime select into ``[0, size)`` (``dynamic_slice``'s
+    out-of-range rule; VMEM reads are not bounds-checked on the chip)."""
+    return jnp.minimum(jnp.maximum(idx, 0), size - 1)
+
+
+def _run_levels(grid: GridSpec, idx: Tuple, op_ref, sel_ref, src_ref, lvl_ref):
+    """The conventional PE-level pipeline over one pixel block, shared by
+    every conventional kernel body.
+
+    ``idx`` prefixes every SMEM read (``(i,)`` for a batched bank with a
+    leading app axis, ``(si, i)`` for a stage-stacked one).  ``src_ref``
+    holds the memory-VC channels ``[C, rows, lanes]``; ``lvl_ref`` is the
+    ``[2, max_w, rows, lanes]`` ping-pong buffer the levels write in
+    turn.  Returns the ref view holding the last level's outputs.  Dense
+    settings are padded to max_w but only the grid's true per-level width
+    is ever read, so pad slots cost nothing.
     """
-    prev = x
-    for lvl in range(grid.num_levels):  # grid structure static, settings not
-        width = grid.pes_per_level[lvl]
-        a_rows = []
-        b_rows = []
-        for slot in range(width):
-            sa = sel_ref[idx + (lvl, slot, 0)]
-            sb = sel_ref[idx + (lvl, slot, 1)]
-            a_rows.append(jax.lax.dynamic_index_in_dim(prev, sa, 0, keepdims=False))
-            b_rows.append(jax.lax.dynamic_index_in_dim(prev, sb, 0, keepdims=False))
-        a = jnp.stack(a_rows, axis=0)
-        b = jnp.stack(b_rows, axis=0)
-        opcodes = jnp.stack([op_ref[idx + (lvl, s)] for s in range(width)])
-        prev = pe_ops.apply_generic(opcodes, a, b)
-    return prev
+    src, n_src = src_ref, grid.num_inputs
+    for lvl in range(grid.num_levels):    # grid structure static, settings not
+        dst = lvl_ref.at[lvl % 2]
+
+        def pe(slot, carry, lvl=lvl, src=src, dst=dst, n_src=n_src):
+            a = src[_clamp(sel_ref[idx + (lvl, slot, 0)], n_src)]
+            b = src[_clamp(sel_ref[idx + (lvl, slot, 1)], n_src)]
+            op = jnp.full(a.shape, op_ref[idx + (lvl, slot)], jnp.int32)
+            dst[slot] = pe_ops.apply_generic(op, a, b)
+            return carry
+
+        jax.lax.fori_loop(0, grid.pes_per_level[lvl], pe, 0)
+        src, n_src = dst, grid.pes_per_level[lvl]
+    return src
 
 
-def _gather_outputs(grid: GridSpec, idx: Tuple, outsel_ref, prev: jnp.ndarray, dtype):
-    rows = [
-        jax.lax.dynamic_index_in_dim(prev, outsel_ref[idx + (k,)], 0, keepdims=False)
-        for k in range(grid.num_outputs)
-    ]
-    return jnp.stack(rows, axis=0).astype(dtype)
-
-
-def _conventional_body(grid: GridSpec, op_ref, sel_ref, out_ref, x_ref, o_ref):
-    """Settings in SMEM; generic PEs; dynamic routing selects.
-
-    op_ref:  SMEM int32 [num_levels, max_w]
-    sel_ref: SMEM int32 [num_levels, max_w, 2]
-    out_ref: SMEM int32 [num_outputs]
-    """
-    x = x_ref[...]                      # [num_inputs, block_n]
-    prev = _level_pipeline(grid, (), op_ref, sel_ref, x)
-    o_ref[...] = _gather_outputs(grid, (), out_ref, prev, x.dtype)
+def _output(grid: GridSpec, idx: Tuple, outsel_ref, last_ref, k):
+    """Output VC ``k``: the last-level row its select names."""
+    return last_ref[_clamp(outsel_ref[idx + (k,)], grid.pes_per_level[-1])]
 
 
 def _pack_settings(grid: GridSpec, config: VCGRAConfig):
@@ -179,31 +216,16 @@ def vcgra_conventional(
     grid: GridSpec,
     config_arrays: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
     x: jnp.ndarray,
-    block_n: int = 1024,
+    block_n: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Conventional-path pallas executor: one executable per *grid*, any
-    application's packed settings arrays accepted at runtime.
-    ``interpret=None`` auto-detects the platform."""
-    interpret = _resolve_interpret(interpret)
-    ops_arr, sel_arr, out_sel = config_arrays
-    n_in, n = x.shape
-    assert n % block_n == 0 and block_n % LANE == 0
-    body = functools.partial(_conventional_body, grid)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n // block_n,),
-        in_specs=[pl.BlockSpec((n_in, block_n), lambda i, *_: (0, i))],
-        out_specs=pl.BlockSpec(
-            (grid.num_outputs, block_n), lambda i, *_: (0, i)
-        ),
-    )
-    return pl.pallas_call(
-        body,
-        out_shape=jax.ShapeDtypeStruct((grid.num_outputs, n), x.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(ops_arr, sel_arr, out_sel, x)
+    application's packed settings arrays accepted at runtime.  It is the
+    batched kernel with one app.  ``interpret=None`` auto-detects the
+    platform."""
+    settings = tuple(jnp.asarray(a)[None] for a in config_arrays)
+    return vcgra_batched(grid, settings, x[None], block_n=block_n,
+                         interpret=interpret)[0]
 
 
 # -- batched megakernels -------------------------------------------------------
@@ -212,97 +234,101 @@ def vcgra_conventional(
 # (``interpreter.batched_overlay_step`` / ``batched_fused_overlay_step``):
 # ONE pallas_call whose grid iterates the app axis, with every tenant's
 # settings bank (PE opcodes, VC mux selects, output selects -- and for the
-# fused variant the ingest plan's tap selects) scalar-prefetched into SMEM.
-# The kernel instance for app ``i`` indexes its own settings rows with
-# ``pl.program_id(0)``, so N different applications execute through one
-# compiled kernel -- the settings-register analogue at fleet scale.
+# fused variant the ingest plan's tap selects and const values)
+# scalar-prefetched into SMEM.  The kernel instance for app ``i`` indexes
+# its own settings rows with ``pl.program_id(0)``, so N different
+# applications execute through one compiled kernel -- the
+# settings-register analogue at fleet scale.
 
 
-def _batched_body(grid: GridSpec, op_ref, sel_ref, outsel_ref, x_ref, o_ref):
-    """One app per grid step over pre-packed channels [1, C, block_n]."""
+def _batched_body(grid: GridSpec, shapes, *refs):
+    """One app per grid step over pre-packed channels
+    ``[1, C, rows, LANE]`` (the pixel axis folded onto sublanes x lanes)."""
+    op_ref, sel_ref, outsel_ref = (_Smem(r, s) for r, s in zip(refs, shapes))
+    x_ref, o_ref, lvl_ref = refs[3:]
     i = pl.program_id(0)
-    x = x_ref[0]
-    prev = _level_pipeline(grid, (i,), op_ref, sel_ref, x)
-    o_ref[0] = _gather_outputs(grid, (i,), outsel_ref, prev, x.dtype)
+    last = _run_levels(grid, (i,), op_ref, sel_ref, x_ref.at[0], lvl_ref)
+    for k in range(grid.num_outputs):
+        o_ref[0, k] = _output(grid, (i,), outsel_ref, last, k)
 
 
 def vcgra_batched(
     grid: GridSpec,
     settings: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
     x: jnp.ndarray,
-    block_n: int = LANE,
+    block_n: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Batched conventional executor: N tenants in ONE pallas_call.
 
     ``settings``: dense-packed banks (ops [N, L, max_w], sel [N, L, max_w, 2],
     out_sel [N, K]) -- see ``ops.pack_settings_batched``.
-    ``x``: [N, num_inputs, B] with ``B % block_n == 0``.
+    ``x``: [N, num_inputs, B]; returns [N, num_outputs, B].  The pixel axis
+    is zero-padded to whole blocks and folded to ``[N, C, B/LANE, LANE]``
+    so every value is a 2-D sublane x lane tile; the padding is sliced
+    back off.  ``block_n`` (pixels per block, a LANE multiple) defaults
+    to one sublane tile of lanes; the compiled path needs it to cover
+    whole sublane tiles (a multiple of ``SUBLANE * LANE``) or the whole
+    padded axis.
     """
     interpret = _resolve_interpret(interpret)
-    ops_arr, sel_arr, out_sel = settings
     n_apps, n_in, b = x.shape
-    assert b % block_n == 0, f"B={b} not a multiple of block_n={block_n}"
-    assert block_n % LANE == 0, f"block_n must be lane-aligned (x{LANE})"
-    body = functools.partial(_batched_body, grid)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_apps, b // block_n),
-        in_specs=[pl.BlockSpec((1, n_in, block_n), lambda i, j, *_: (i, 0, j))],
-        out_specs=pl.BlockSpec(
-            (1, grid.num_outputs, block_n), lambda i, j, *_: (i, 0, j)
-        ),
+    if block_n is None:
+        br = min(-(-b // LANE), SUBLANE)
+    else:
+        assert block_n % LANE == 0, f"block_n must be lane-aligned (x{LANE})"
+        br = block_n // LANE
+    n_rows = _round_up(-(-b // LANE), br)
+    assert interpret or br % SUBLANE == 0 or br == n_rows, (
+        f"compiled channel kernel needs whole sublane tiles per block: "
+        f"block_n={br * LANE} is not a multiple of {SUBLANE * LANE} and "
+        f"does not cover the padded batch ({n_rows * LANE})"
     )
-    return pl.pallas_call(
-        body,
-        out_shape=jax.ShapeDtypeStruct((n_apps, grid.num_outputs, b), x.dtype),
+    xp = jnp.pad(x, ((0, 0), (0, 0), (0, n_rows * LANE - b)))
+    xp = xp.reshape(n_apps, n_in, n_rows, LANE)
+    K = grid.num_outputs
+    smem, shapes = _smem_operands(*settings)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(smem),
+        grid=(n_apps, n_rows // br),
+        in_specs=[pl.BlockSpec((1, n_in, br, LANE),
+                               lambda i, j, *_: (i, 0, j, 0))],
+        out_specs=pl.BlockSpec((1, K, br, LANE),
+                               lambda i, j, *_: (i, 0, j, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, max(grid.pes_per_level), br, LANE), x.dtype),
+        ],
+    )
+    y = pl.pallas_call(
+        functools.partial(_batched_body, grid, shapes),
+        out_shape=jax.ShapeDtypeStruct((n_apps, K, n_rows, LANE), x.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(ops_arr, sel_arr, out_sel, x)
+    )(*smem, xp)
+    return y.reshape(n_apps, K, n_rows * LANE)[:, :, :b]
 
 
-def _fused_batched_body(
-    grid: GridSpec, radius: int, tile_rows: int,
-    tap_sel_ref, op_ref, sel_ref, outsel_ref, const_ref, frames_ref, o_ref,
-    slabs_ref, dma_sems_ref,
-):
-    """Fused-ingest megakernel body: one row-haloed slab -> outputs, per
-    (app, row-tile) grid step, with the slab streamed HBM->VMEM by an
-    in-kernel double-buffered DMA.
+def _slab_dma_pipeline(frames_ref, slabs_ref, dma_sems_ref, tile_rows: int):
+    """The in-kernel double-buffered HBM->VMEM slab stream shared by the
+    fused and pipeline megakernels.  Starts the NEXT grid step's
+    ``[slab_rows, W]`` window into the other buffer, blocks on this
+    step's own, and returns the slot holding it.
 
-    ``frames_ref`` is the whole zero-row-padded frame stack
-    ``[N, T*tile_rows + 2r, W]`` left in HBM (``memory_space=ANY`` -- the
-    block pipeline never copies it); each grid step DMAs its own
-    ``[tile_rows + 2r, W]`` halo window straight out of the un-duplicated
-    frame into one of two VMEM slab buffers (``slabs_ref``) and *starts
-    the next step's window into the other buffer before computing*, so
-    tile t+1 streams in while tile t's PE pipeline executes.  The buffer
-    slot rotates on the LINEARIZED step index ``i*T + t`` (rotating on the
-    tile index alone desynchronizes producer and consumer at app
-    boundaries whenever T is odd).  Halo rows are re-read from HBM only at
-    tile seams (``2r`` rows per interior seam) -- never duplicated into an
-    HBM-resident slab tensor like the old host-side pre-slice.
-
-    The rest is the whole Pixie data path inside the kernel instance: the
-    slab is column-padded and sliced into the tap bank (line-buffer
-    formation; offsets are trace-time constants), each memory-VC channel
-    *selects* its producer from the bank via the SMEM tap_sel row (ingest
-    plans are runtime settings, like VC muxes), then the conventional PE
-    pipeline executes on the channels -- all without the slab ever leaving
-    VMEM.  The untiled layout is simply T == 1: one window covering the
-    whole padded frame, same body, no second buffer ever filled.
+    The buffer slot rotates on the LINEARIZED step index ``i*T + t``
+    (rotating on the tile index alone desynchronizes producer and consumer
+    at app boundaries whenever T is odd).  Next step's (app, tile) wraps
+    the tile axis, so the app boundary prefetches tile 0 of app i+1.
     """
     i = pl.program_id(0)
     t = pl.program_id(1)
     n_tiles = pl.num_programs(1)
     step = i * n_tiles + t
     slot = jax.lax.rem(step, 2)
-    r = radius
-    tr = tile_rows
+    slab_rows = slabs_ref.shape[1]
 
     def slab_dma(slot, app, tile):
         return pltpu.make_async_copy(
-            frames_ref.at[app, pl.ds(tile * tr, tr + 2 * r), :],
+            frames_ref.at[app, pl.ds(tile * tile_rows, slab_rows), :],
             slabs_ref.at[slot],
             dma_sems_ref.at[slot],
         )
@@ -311,10 +337,6 @@ def _fused_batched_body(
     def _():
         slab_dma(0, 0, 0).start()        # warm-up: first window, slot 0
 
-    # Start the NEXT step's window into the other buffer, then block on
-    # this step's own DMA: the prefetch is in flight across the wait and
-    # the whole PE pipeline below.  Next step's (app, tile) wraps the tile
-    # axis so the app boundary prefetches tile 0 of app i+1.
     next_t = jax.lax.rem(t + 1, n_tiles)
     next_i = i + jax.lax.div(t + 1, n_tiles)
 
@@ -323,26 +345,174 @@ def _fused_batched_body(
         slab_dma(1 - slot, next_i, next_t).start()
 
     slab_dma(slot, i, t).wait()
-    slab = slabs_ref[slot]               # [tile_rows + 2r, W] haloed rows
-    W = slab.shape[1]
-    dtype = slab.dtype
-    padded = jnp.pad(slab, ((0, 0), (r, r)))   # columns only; rows came in
-    taps = [
-        padded[r + dj : r + dj + tr, r + di : r + di + W].reshape(tr * W)
-        for dj, di in tap_offsets(radius)
+    return slot
+
+
+def _form_bank(radius: int, rows: int, x_ref, bank_ref) -> None:
+    """Line-buffer formation: the tap bank of ``rows`` output rows from
+    the haloed input rows ``x_ref [>= rows + 2*radius, W]``.
+
+    Tap ``(dj, di)`` is the row window starting at ``radius + dj`` (a
+    static sublane offset) shifted by ``di`` columns with a lane rotate
+    whose wrapped-around columns are zeroed -- exactly
+    ``form_tap_bank``'s zero border (the column pad of the compiled
+    layout is zeros too).
+    """
+    W = x_ref.shape[-1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, W), 1)
+    for t, (dj, di) in enumerate(tap_offsets(radius)):
+        v = x_ref[pl.ds(radius + dj, rows), :]
+        if di:
+            v = pltpu.roll(v, (-di) % W, 1)
+            ok = jnp.logical_and(col + di >= 0, col + di < W)
+            v = jnp.where(ok, v, jnp.zeros_like(v))
+        bank_ref[t, pl.ds(0, rows), :] = v
+
+
+def _blocks(rows: int, W: int, align: int):
+    """The ``[row, lane]`` pixel block the datapath loop walks: one
+    sublane tile by up to 512 lanes on the compiled path (a few vregs per
+    value, so the PE datapath stays in registers), the whole region in
+    interpret mode."""
+    if align == 1:
+        return rows, W
+    return SUBLANE, next(c for c in (512, 256, LANE) if W % c == 0)
+
+
+def _datapath(grid: GridSpec, radius: int, rows: int, W: int, align: int,
+              idx: Tuple, refs, store) -> None:
+    """Channel production + PE pipeline + output store over a ``[rows,
+    W]`` region whose tap bank is already formed, block by block.
+
+    Each memory-VC channel *selects* its producer from the bank by its
+    SMEM tap select (ingest plans are runtime settings, like VC muxes); a
+    select of the trailing zero row takes the channel's const value
+    instead.  ``store(last_ref, r0, c0)`` consumes the last level of the
+    block at row ``r0``, lane ``c0``.
+    """
+    tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref, lvl_ref = refs
+    rb, cb = _blocks(rows, W, align)
+    if chan_ref.shape[1:] != (rb, cb):
+        # Interpret-mode pipeline stages below the tallest one: view the
+        # block-sized corner of the scratch (compiled blocks are uniform).
+        chan_ref = chan_ref.at[:, pl.ds(0, rb), pl.ds(0, cb)]
+        lvl_ref = lvl_ref.at[:, :, pl.ds(0, rb), pl.ds(0, cb)]
+    n_cb = W // cb
+    zero_row = (2 * radius + 1) ** 2
+    dtype = chan_ref.dtype
+
+    def block(b, carry):
+        r0 = pl.multiple_of((b // n_cb) * rb, rb)
+        c0 = pl.multiple_of((b % n_cb) * cb, cb)
+        for c in range(grid.num_inputs):
+            tap = tap_ref[idx + (c,)]
+            row = bank_ref[_clamp(tap, zero_row), pl.ds(r0, rb), pl.ds(c0, cb)]
+            const = jnp.full(row.shape, const_ref[idx + (c,)]).astype(dtype)
+            use_const = jnp.full(row.shape, tap, jnp.int32) == zero_row
+            chan_ref[c] = jnp.where(use_const, const, row)
+        store(_run_levels(grid, idx, op_ref, sel_ref, chan_ref, lvl_ref), r0, c0)
+        return carry
+
+    jax.lax.fori_loop(0, (rows // rb) * n_cb, block, 0)
+
+
+def _fused_batched_body(grid: GridSpec, radius: int, tile_rows: int,
+                        align: int, shapes, *refs):
+    """Fused-ingest megakernel body: one row-haloed slab -> outputs, per
+    (app, row-tile) grid step, with the slab streamed HBM->VMEM by an
+    in-kernel double-buffered DMA.
+
+    ``frames_ref`` is the whole zero-padded frame stack left in HBM
+    (``memory_space=ANY`` -- the block pipeline never copies it); each
+    grid step DMAs its own ``[tile_rows + 2r, W]`` halo window straight
+    out of the un-duplicated frame into one of two VMEM slab buffers
+    (``slabs_ref``) and *starts the next step's window into the other
+    buffer before computing*, so tile t+1 streams in while tile t's PE
+    pipeline executes (see :func:`_slab_dma_pipeline`).  Halo rows are
+    re-read from HBM only at tile seams (``2r`` rows per interior seam)
+    -- never duplicated into an HBM-resident slab tensor.
+
+    The rest is the whole Pixie data path inside the kernel instance: the
+    tap bank (:func:`_form_bank`), then the memory-VC channels and the
+    conventional PE pipeline block by block (:func:`_datapath`) -- all
+    without the slab ever leaving VMEM.  The untiled layout is simply
+    T == 1: one window covering the whole padded frame, same body, no
+    second buffer ever filled.
+    """
+    tap_ref, op_ref, sel_ref, outsel_ref, const_ref = (
+        _Smem(r, s) for r, s in zip(refs, shapes))
+    (frames_ref, o_ref, slabs_ref, dma_sems_ref,
+     bank_ref, chan_ref, lvl_ref) = refs[len(shapes):]
+    i = pl.program_id(0)
+    slot = _slab_dma_pipeline(frames_ref, slabs_ref, dma_sems_ref, tile_rows)
+    _form_bank(radius, tile_rows, slabs_ref.at[slot], bank_ref)
+
+    def store(last, r0, c0):
+        for k in range(grid.num_outputs):
+            o_ref[0, k, pl.ds(r0, last.shape[-2]), pl.ds(c0, last.shape[-1])] = (
+                _output(grid, (i,), outsel_ref, last, k))
+
+    _datapath(grid, radius, tile_rows, o_ref.shape[-1], align, (i,),
+              (tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref,
+               lvl_ref), store)
+
+
+def _round_up(n: int, align: int) -> int:
+    return -(-int(n) // align) * align
+
+
+def _frame_layout(H: int, W: int, halo: int, tile_rows, grid: GridSpec,
+                  interpret: bool):
+    """Row alignment, tile height, tile count and the padded output canvas
+    ``(Hp, Wp)`` of one fused megakernel call.
+
+    The compiled path works in whole sublane tiles: tile heights, DMA
+    slab heights and every in-kernel row count are multiples of
+    ``SUBLANE`` (a single tile is the frame rounded up), and W is padded
+    to a LANE multiple -- the output block ``(1, K, tile_rows, Wp)``,
+    the HBM window slices, the VMEM ref slices and the lane rotates need
+    all of it.  The zero pad rows and columns read exactly like the frame
+    border and are sliced back off.  Interpret mode has no layout
+    constraint (``align == 1``) and keeps the frame's own shape.
+    """
+    align = 1 if interpret else SUBLANE
+    tr = resolve_tile_rows(tile_rows, H, W, halo, grid,
+                           align=None if interpret else SUBLANE)
+    n_tiles = num_row_tiles(H, tr)
+    if n_tiles == 1:
+        tr = _round_up(tr, align)
+    assert tr % align == 0, (
+        f"compiled megakernel needs whole sublane tiles: tile_rows={tr} "
+        f"is not a multiple of {SUBLANE} and does not cover H={H}; pick "
+        f"another tile_rows or pass interpret=True"
+    )
+    return align, tr, n_tiles, n_tiles * tr, _round_up(W, 1 if interpret else LANE)
+
+
+def _pad_frames(images, top: int, n_tiles: int, tile_rows: int,
+                slab_rows: int, Wp: int):
+    """Host side of a fused pallas_call: ONLY the zero pad (``top`` border
+    rows; ragged-tile remainder + the last slab's reach at the bottom;
+    lane remainder at the right) -- the halo windows themselves are
+    sliced by the in-kernel DMA, never materialized in HBM."""
+    _, H, W = images.shape
+    bottom = (n_tiles - 1) * tile_rows + slab_rows - H - top
+    return jnp.pad(images, ((0, 0), (top, bottom), (0, Wp - W)))
+
+
+def _fused_scratch(grid: GridSpec, radius: int, slab_rows: int, rows: int,
+                   W: int, align: int, dtype):
+    """VMEM scratch of a fused body: the DMA double buffer + semaphores,
+    the tap bank of ``rows`` rows, and the block-sized channels and level
+    ping-pong buffer."""
+    rb, cb = _blocks(rows, W, align)
+    return [
+        pltpu.VMEM((2, slab_rows, W), dtype),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM(((2 * radius + 1) ** 2, rows, W), dtype),
+        pltpu.VMEM((grid.num_inputs, rb, cb), dtype),
+        pltpu.VMEM((2, max(grid.pes_per_level), rb, cb), dtype),
     ]
-    taps.append(jnp.zeros((tr * W,), dtype))   # const/padding producer row
-    bank = jnp.stack(taps, axis=0)             # [T+1, tile_rows*W]
-    zero_row = len(taps) - 1
-    consts = const_ref[0]                      # [C] in grid dtype
-    chans = []
-    for c in range(grid.num_inputs):
-        tap = tap_sel_ref[i, c]
-        row = jax.lax.dynamic_index_in_dim(bank, tap, 0, keepdims=False)
-        chans.append(jnp.where(tap == zero_row, consts[c], row))
-    x = jnp.stack(chans, axis=0)               # [C, tile_rows*W] channels
-    prev = _level_pipeline(grid, (i,), op_ref, sel_ref, x)
-    o_ref[0] = _gather_outputs(grid, (i,), outsel_ref, prev, dtype)
 
 
 def vcgra_fused_batched(
@@ -361,27 +531,24 @@ def vcgra_fused_batched(
 
     ``settings``: dense banks (ops [N, L, max_w], sel [N, L, max_w, 2],
     out_sel [N, K]); ``ingests``: (tap_sel int32 [N, C], const_vals [N, C]
-    in grid dtype); ``images``: [N, H, W], cast to the grid dtype at entry
-    exactly like the XLA path's ``form_tap_bank`` (so parity holds even
-    for frames arriving in another dtype).  Returns [N, num_outputs, H*W]
-    in the grid dtype.
+    in grid dtype), all scalar-prefetched into SMEM; ``images``: [N, H, W],
+    cast to the grid dtype at entry exactly like the XLA path's
+    ``form_tap_bank`` (so parity holds even for frames arriving in another
+    dtype).  Returns [N, num_outputs, H*W] in the grid dtype.
 
     Blocking: the pallas grid iterates (app, row-tile) over the ONE
-    zero-row-padded frame stack ``[N, T*tile_rows + 2r, W]``, which stays
-    in HBM (``memory_space=ANY``) -- no host-side halo slab tensor is ever
-    materialized.  ``tile_rows`` (int, ``tiling.TILE_AUTO`` or None =
-    whole frame) fixes the tile height; each grid step's
-    ``[tile_rows + 2*radius, W]`` halo window is streamed HBM->VMEM by the
-    kernel's own double-buffered ``make_async_copy`` pipeline (see
-    ``_fused_batched_body``): tile t+1's window is in flight while tile
-    t's PE pipeline executes, each frame row crosses HBM->VMEM once, and
-    halo rows are re-read only at tile seams.  VMEM holds
-    ``O((T+1 + max_level_width + 2) * tile_rows * W)`` elements at a time
-    (the +2 is both DMA slabs) instead of the whole frame + tap bank; the
-    budget heuristic (``tiling.slab_rows_per_budget``) accounts for
-    exactly this set.  ``tile_rows`` not dividing H is padded with zero
-    rows and sliced back -- bitwise-exact, the padding is read only as the
-    bottom halo.
+    zero-padded frame stack, which stays in HBM (``memory_space=ANY``) --
+    no host-side halo slab tensor is ever materialized.  ``tile_rows``
+    (int, ``tiling.TILE_AUTO`` or None = whole frame) fixes the tile
+    height (see :func:`_frame_layout`); each grid step's ``[tile_rows +
+    2*radius, Wp]`` halo window is streamed HBM->VMEM by the kernel's own
+    double-buffered ``make_async_copy`` pipeline, so tile t+1's window is
+    in flight while tile t's PE pipeline executes, each frame row crosses
+    HBM->VMEM once, and halo rows are re-read only at tile seams.  VMEM
+    holds the two slabs and the ``taps * tile_rows * Wp`` tap bank that
+    ``tiling.slab_rows_per_budget`` budgets for, plus block-sized channel
+    and level buffers.  Padding rows and columns are zeros read only as
+    border and sliced back off -- bitwise-exact.
 
     (Why not ``pltpu.emit_pipeline``: its BlockSpec grids express
     *disjoint* blocks -- index maps are multiplied by the block shape --
@@ -390,68 +557,34 @@ def vcgra_fused_batched(
     would build, with the overlapping source windows it cannot express.)
     """
     interpret = _resolve_interpret(interpret)
-    ops_arr, sel_arr, out_sel = settings
     tap_sel, const_vals = ingests
     images = jnp.asarray(images, grid.dtype)
     n_apps, H, W = images.shape
     r = radius
-    # ONE tile-height definition for the heuristic, the XLA twin and this
-    # kernel (tiling.resolve_tile_rows); the compiled path asks it for a
-    # lane-aligned AUTO pick, so the loud assert below fires with the
-    # already-rounded value.
-    tr = resolve_tile_rows(tile_rows, H, W, r, grid,
-                           lane_align=None if interpret else LANE)
-    n_tiles = num_row_tiles(H, tr)
-    Hp = n_tiles * tr
-    # The compiled (real-TPU) path needs a lane-aligned pixel block; fail
-    # with a clear message instead of an obscure Mosaic lowering error.
-    # The fleet's pow-2 canvas bucketing (min side 16) satisfies this for
-    # the untiled layout and, with resolve_tile_rows' lane_align rounding,
-    # for AUTO tiling; explicit tiled callers must pick lane-friendly tile
-    # heights themselves.  Interpret mode (CPU/GPU CI) has no layout
-    # constraint.
-    assert interpret or (tr * W) % LANE == 0, (
-        f"compiled megakernel needs a lane-aligned pixel block: "
-        f"tile_rows*W={tr}*{W}={tr * W} is not a multiple of {LANE}; pad "
-        f"the canvas (the fleet's pow-2 bucketing does), pick another "
-        f"tile_rows, or pass interpret=True"
-    )
-    # Host side of the pallas_call: ONLY the zero-row pad (radius rows of
-    # border top, radius + ragged-tile remainder bottom) -- the halo
-    # windows themselves are sliced by the in-kernel DMA, never
-    # materialized in HBM.
-    frames = jnp.pad(images, ((0, 0), (r, Hp - H + r), (0, 0)))
-    body = functools.partial(_fused_batched_body, grid, radius, tr)
+    align, tr, n_tiles, Hp, Wp = _frame_layout(H, W, r, tile_rows, grid,
+                                               interpret)
+    slab_rows = _round_up(tr + 2 * r, align)
+    frames = _pad_frames(images, r, n_tiles, tr, slab_rows, Wp)
+    K = grid.num_outputs
+    smem, shapes = _smem_operands(jnp.asarray(tap_sel, jnp.int32), *settings,
+                                  const_vals)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,          # tap_sel, ops, sel, out_sel -> SMEM
+        num_scalar_prefetch=len(smem),
         grid=(n_apps, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, grid.num_inputs), lambda i, t, *_: (i, 0)),
-            # The padded frame stack stays in HBM; the kernel's DMA
-            # pipeline owns the HBM->VMEM movement.
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec(
-            # Row-major flattening makes tile t's pixels contiguous: block
-            # t of the pixel axis IS the tile's [tile_rows, W] rows.
-            (1, grid.num_outputs, tr * W), lambda i, t, *_: (i, 0, t)
-        ),
-        scratch_shapes=[
-            # The double buffer: two in-flight halo slabs + their DMA
-            # completion semaphores.
-            pltpu.VMEM((2, tr + 2 * r, W), images.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        # The padded frame stack stays in HBM; the kernel's DMA pipeline
+        # owns the HBM->VMEM movement.
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, K, tr, Wp), lambda i, t, *_: (i, 0, t, 0)),
+        scratch_shapes=_fused_scratch(grid, r, slab_rows, tr, Wp, align,
+                                      images.dtype),
     )
     y = pl.pallas_call(
-        body,
-        out_shape=jax.ShapeDtypeStruct(
-            (n_apps, grid.num_outputs, Hp * W), images.dtype
-        ),
+        functools.partial(_fused_batched_body, grid, radius, tr, align, shapes),
+        out_shape=jax.ShapeDtypeStruct((n_apps, K, Hp, Wp), images.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(tap_sel, ops_arr, sel_arr, out_sel, const_vals, frames)
-    return y[:, :, : H * W] if Hp != H else y
+    )(*smem, frames)
+    return y[:, :, :H, :W].reshape(n_apps, K, H * W)
 
 
 # -- multi-stage pipeline megakernel -------------------------------------------
@@ -459,15 +592,13 @@ def vcgra_fused_batched(
 # The device-resident chain executor (``core/plan.py`` pipeline axis): a
 # depth-S application chain runs as ONE pallas_call whose per-(app, tile)
 # instance executes every stage back to back over the same VMEM slab --
-# the PR 7 DMA pipeline amortizes across the whole chain instead of
-# paying one HBM round trip per stage.
+# the DMA pipeline amortizes across the whole chain instead of paying one
+# HBM round trip per stage.
 
 
-def _pipeline_batched_body(
-    grid: GridSpec, radii: Tuple[int, ...], tile_rows: int,
-    tap_ref, op_ref, sel_ref, outsel_ref, outch_ref, hw_ref,
-    const_ref, frames_ref, o_ref, slabs_ref, dma_sems_ref,
-):
+def _pipeline_batched_body(grid: GridSpec, radii: Tuple[int, ...],
+                           tile_rows: int, stage_rows: Tuple[int, ...],
+                           align: int, shapes, *refs):
     """Multi-stage trapezoid body: one haloed slab -> final-stage outputs.
 
     The DMA schedule is exactly ``_fused_batched_body``'s double buffer,
@@ -475,92 +606,71 @@ def _pipeline_batched_body(
     ``tile_rows`` final rows, stage 0 must consume ``tile_rows + 2R`` input
     rows, and each stage shaves its own ``2 * r_i`` -- a trapezoid of
     working regions narrowing toward the output tile.  Stage *i* therefore
-    computes ``tile_rows + 2 * reach_i`` rows where ``reach_i`` is the sum
-    of the *downstream* radii (rows later stages still need as halo).
+    computes ``stage_rows[i] >= tile_rows + 2 * reach_i`` rows where
+    ``reach_i`` is the sum of the *downstream* radii (rows later stages
+    still need as halo); rows past the trapezoid only ever feed rows past
+    the next stage's trapezoid.
 
     Between stages the selected output channel (``outch_ref``, a runtime
     setting like every mux select) is re-masked against the app's true
-    frame extent (``hw_ref``): slab rows outside ``[0, h)`` and columns
-    outside ``[0, w)`` are canvas/halo padding whose *stage outputs* are
-    generally nonzero (a threshold PE emits GT(0, c) there), but the next
-    stage's line buffers must read zeros -- the same invariant the XLA
-    chain keeps with ``interpreter.valid_pixel_mask``, which is what makes
-    fused-vs-staged bitwise parity hold.  The global row of local row
-    ``j`` in stage *i*'s output region is ``t * tile_rows - reach_i + j``.
+    frame extent (``hw_ref``) into ``x_ref``, the next stage's input:
+    slab rows outside ``[0, h)`` and columns outside ``[0, w)`` are
+    canvas/halo padding whose *stage outputs* are generally nonzero (a
+    threshold PE emits GT(0, c) there), but the next stage's line buffers
+    must read zeros -- the same invariant the XLA chain keeps with
+    ``interpreter.valid_pixel_mask``, which is what makes fused-vs-staged
+    bitwise parity hold.  The global row of local row ``j`` in stage
+    *i*'s output region is ``t * tile_rows - reach_i + j``.
 
     Settings banks carry a leading stage axis (``[S, N, ...]``; the
-    ``(si, i)`` SMEM index prefix reuses the shared ``_level_pipeline`` /
-    ``_gather_outputs`` helpers), so one compiled kernel serves every
-    depth-S chain on the grid -- the settings-register contract at chain
-    scale.  The final stage writes straight to the output block, unmasked,
-    like the single-stage kernel (callers slice the canvas).
+    ``(si, i)`` SMEM index prefix reuses the shared helpers), so one
+    compiled kernel serves every depth-S chain on the grid -- the
+    settings-register contract at chain scale.  The final stage writes
+    straight to the output block, unmasked, like the single-stage kernel
+    (callers slice the canvas).
     """
+    (tap_ref, op_ref, sel_ref, outsel_ref, outch_ref, hw_ref,
+     const_ref) = (_Smem(r, s) for r, s in zip(refs, shapes))
+    (frames_ref, o_ref, slabs_ref, dma_sems_ref, x_ref,
+     bank_ref, chan_ref, lvl_ref) = refs[len(shapes):]
     i = pl.program_id(0)
     t = pl.program_id(1)
-    n_tiles = pl.num_programs(1)
-    step = i * n_tiles + t
-    slot = jax.lax.rem(step, 2)
-    R = sum(radii)
-    tr = tile_rows
-
-    def slab_dma(slot, app, tile):
-        return pltpu.make_async_copy(
-            frames_ref.at[app, pl.ds(tile * tr, tr + 2 * R), :],
-            slabs_ref.at[slot],
-            dma_sems_ref.at[slot],
-        )
-
-    @pl.when(step == 0)
-    def _():
-        slab_dma(0, 0, 0).start()
-
-    next_t = jax.lax.rem(t + 1, n_tiles)
-    next_i = i + jax.lax.div(t + 1, n_tiles)
-
-    @pl.when(step + 1 < pl.num_programs(0) * n_tiles)
-    def _():
-        slab_dma(1 - slot, next_i, next_t).start()
-
-    slab_dma(slot, i, t).wait()
-    x = slabs_ref[slot]                  # [tile_rows + 2R, W] haloed rows
-    W = x.shape[1]
-    dtype = x.dtype
+    slot = _slab_dma_pipeline(frames_ref, slabs_ref, dma_sems_ref, tile_rows)
+    src = slabs_ref.at[slot]             # haloed rows of the whole chain
+    W = o_ref.shape[-1]
+    last_stage = len(radii) - 1
     for si, r in enumerate(radii):       # chain static; settings runtime
         reach = sum(radii[si + 1:])
-        h_out = tr + 2 * reach
-        padded = jnp.pad(x, ((0, 0), (r, r)))   # columns only
-        taps = [
-            padded[r + dj : r + dj + h_out, r + di : r + di + W].reshape(
-                h_out * W
-            )
-            for dj, di in tap_offsets(r)
-        ]
-        taps.append(jnp.zeros((h_out * W,), dtype))
-        bank = jnp.stack(taps, axis=0)
-        zero_row = len(taps) - 1
-        consts = const_ref[si, 0]        # [C] in grid dtype
-        chans = []
-        for c in range(grid.num_inputs):
-            tap = tap_ref[si, i, c]
-            row = jax.lax.dynamic_index_in_dim(bank, tap, 0, keepdims=False)
-            chans.append(jnp.where(tap == zero_row, consts[c], row))
-        xc = jnp.stack(chans, axis=0)    # [C, h_out*W] stage channels
-        prev = _level_pipeline(grid, (si, i), op_ref, sel_ref, xc)
-        if si == len(radii) - 1:
-            o_ref[0] = _gather_outputs(grid, (si, i), outsel_ref, prev, dtype)
+        rows = stage_rows[si]
+        _form_bank(r, rows, src, bank_ref)
+
+        if si == last_stage:
+            def store(last, r0, c0, si=si):
+                for k in range(grid.num_outputs):
+                    o_ref[0, k, pl.ds(r0, last.shape[-2]),
+                          pl.ds(c0, last.shape[-1])] = _output(
+                        grid, (si, i), outsel_ref, last, k)
         else:
-            y = jax.lax.dynamic_index_in_dim(
-                prev, outch_ref[si, i], 0, keepdims=False
-            ).reshape(h_out, W).astype(dtype)
-            grow = (t * tr - reach) + jax.lax.broadcasted_iota(
-                jnp.int32, (h_out, W), 0
-            )
-            gcol = jax.lax.broadcasted_iota(jnp.int32, (h_out, W), 1)
-            valid = jnp.logical_and(
-                jnp.logical_and(grow >= 0, grow < hw_ref[i, 0]),
-                gcol < hw_ref[i, 1],
-            )
-            x = jnp.where(valid, y, jnp.zeros_like(y))
+            def store(last, r0, c0, si=si, reach=reach):
+                # The forwarded channel is output k = out_ch of the stage,
+                # i.e. last-level row out_sel[k] (forward_stage_output).
+                k = _clamp(outch_ref[si, i], grid.num_outputs)
+                y = _output(grid, (si, i), outsel_ref, last, k)
+                shape = y.shape
+                grow = (t * tile_rows - reach + r0) + (
+                    jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+                gcol = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                valid = jnp.logical_and(
+                    jnp.logical_and(grow >= 0, grow < hw_ref[i, 0]),
+                    gcol < hw_ref[i, 1],
+                )
+                x_ref[pl.ds(r0, shape[0]), pl.ds(c0, shape[1])] = jnp.where(
+                    valid, y, jnp.zeros_like(y))
+
+        _datapath(grid, r, rows, W, align, (si, i),
+                  (tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref,
+                   lvl_ref), store)
+        src = x_ref
 
 
 def vcgra_pipeline_batched(
@@ -586,60 +696,53 @@ def vcgra_pipeline_batched(
     [N, 2] true (rows, cols) of each app's frame inside the canvas;
     ``images``: [N, H, W].  Returns [N, num_outputs, H*W] in grid dtype.
 
-    The frame stack is zero-row-padded by the chain's TOTAL radius
-    ``R = sum(radii)`` and stays in HBM; each (app, row-tile) step DMAs
-    one ``[tile_rows + 2R, W]`` window into the 2-slot VMEM double buffer
-    and runs the whole stage trapezoid on it (see
-    ``_pipeline_batched_body``), so every frame row crosses HBM->VMEM
-    once *per chain*, not once per stage.
+    The frame stack is zero-padded by the chain's TOTAL radius
+    ``R = sum(radii)`` rows (plus the compiled layout's alignment, see
+    :func:`_frame_layout`) and stays in HBM; each (app, row-tile) step
+    DMAs one haloed window into the 2-slot VMEM double buffer and runs
+    the whole stage trapezoid on it (see ``_pipeline_batched_body``), so
+    every frame row crosses HBM->VMEM once *per chain*, not once per
+    stage.
     """
     interpret = _resolve_interpret(interpret)
     radii = tuple(int(r) for r in radii)
-    ops_arr, sel_arr, out_sel = settings
     tap_sel, const_vals = ingests
     images = jnp.asarray(images, grid.dtype)
     n_apps, H, W = images.shape
     R = sum(radii)
-    tr = resolve_tile_rows(tile_rows, H, W, R, grid,
-                           lane_align=None if interpret else LANE)
-    n_tiles = num_row_tiles(H, tr)
-    Hp = n_tiles * tr
-    assert interpret or (tr * W) % LANE == 0, (
-        f"compiled pipeline megakernel needs a lane-aligned pixel block: "
-        f"tile_rows*W={tr}*{W}={tr * W} is not a multiple of {LANE}; pad "
-        f"the canvas (the fleet's pow-2 bucketing does), pick another "
-        f"tile_rows, or pass interpret=True"
+    align, tr, n_tiles, Hp, Wp = _frame_layout(H, W, R, tile_rows, grid,
+                                               interpret)
+    # Rows each stage computes: its trapezoid height tile_rows + 2*reach,
+    # rounded to whole sublane tiles on the compiled path.  Stage 0 reads
+    # the slab; later stages read x_ref, which holds the previous stage's
+    # rows; the bank is sized by stage 0, the tallest.
+    rows = tuple(_round_up(tr + 2 * sum(radii[si + 1:]), align)
+                 for si in range(len(radii)))
+    slab_rows = _round_up(max(tr + 2 * R, rows[0] + 2 * radii[0]), align)
+    x_rows = max([rows[si] + 2 * radii[si] for si in range(1, len(radii))]
+                 + list(rows[:-1]))
+    frames = _pad_frames(images, R, n_tiles, tr, slab_rows, Wp)
+    K = grid.num_outputs
+    scratch = _fused_scratch(grid, max(radii), slab_rows, rows[0], Wp, align,
+                             images.dtype)
+    scratch.insert(2, pltpu.VMEM((x_rows, Wp), images.dtype))
+    smem, shapes = _smem_operands(
+        jnp.asarray(tap_sel, jnp.int32), *settings,
+        jnp.asarray(out_chs, jnp.int32), jnp.asarray(hw, jnp.int32),
+        const_vals,
     )
-    frames = jnp.pad(images, ((0, 0), (R, Hp - H + R), (0, 0)))
-    n_stages = len(radii)
-    body = functools.partial(_pipeline_batched_body, grid, radii, tr)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,   # tap_sel, ops, sel, out_sel, out_ch, hw
+        num_scalar_prefetch=len(smem),
         grid=(n_apps, n_tiles),
-        in_specs=[
-            pl.BlockSpec(
-                (n_stages, 1, grid.num_inputs), lambda i, t, *_: (0, i, 0)
-            ),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, grid.num_outputs, tr * W), lambda i, t, *_: (i, 0, t)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, tr + 2 * R, W), images.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, K, tr, Wp), lambda i, t, *_: (i, 0, t, 0)),
+        scratch_shapes=scratch,
     )
     y = pl.pallas_call(
-        body,
-        out_shape=jax.ShapeDtypeStruct(
-            (n_apps, grid.num_outputs, Hp * W), images.dtype
-        ),
+        functools.partial(_pipeline_batched_body, grid, radii, tr, rows,
+                          align, shapes),
+        out_shape=jax.ShapeDtypeStruct((n_apps, K, Hp, Wp), images.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(
-        jnp.asarray(tap_sel, jnp.int32), ops_arr, sel_arr, out_sel,
-        jnp.asarray(out_chs, jnp.int32), jnp.asarray(hw, jnp.int32),
-        const_vals, frames,
-    )
-    return y[:, :, : H * W] if Hp != H else y
+    )(*smem, frames)
+    return y[:, :, :H, :W].reshape(n_apps, K, H * W)

@@ -76,8 +76,6 @@ def _mem_analysis(compiled) -> Dict[str, Optional[float]]:
 def _cost_analysis(compiled) -> Dict[str, float]:
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
         return {k: float(v) for k, v in ca.items() if np.isscalar(v)}
     except Exception:
         return {}

@@ -248,12 +248,7 @@ def moe_ffn_ep(
         )
         return y.reshape(xb.shape), aux
 
-    if hasattr(jax, "shard_map"):
-        smap = functools.partial(jax.shard_map, check_vma=False)
-    else:  # jax < 0.5: experimental API, check_rep instead of check_vma
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        smap = functools.partial(_shard_map, check_rep=False)
+    smap = functools.partial(jax.shard_map, check_vma=False)
     y, aux = smap(
         per_shard,
         mesh=mesh,

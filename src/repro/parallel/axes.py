@@ -26,7 +26,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _ambient_mesh():
@@ -143,15 +143,9 @@ def build_mesh(spec: MeshSpec) -> Optional[Mesh]:
     return Mesh(devs.reshape(spec.app, spec.rows), (APP_AXIS, ROW_AXIS))
 
 
-def _shard_map_impl():
-    """Version-compat shard_map (same dance as models/moe.py): jax>=0.6
-    exposes jax.shard_map (check_vma), older jax ships it under
-    jax.experimental (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return functools.partial(jax.shard_map, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return functools.partial(_shard_map, check_rep=False)
+#: ``jax.shard_map`` with the replication check off (the overlay bodies
+#: mix per-app and replicated values freely).
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def app_mesh(devices: int, axis: str = APP_AXIS) -> Optional[Mesh]:
@@ -182,7 +176,7 @@ def shard_apps(fn: Callable, mesh: Mesh, num_args: int,
     Callers must pad N to a multiple of the mesh size first
     (``plan._with_app_padding``)."""
     spec = P(axis)
-    return _shard_map_impl()(
+    return _shard_map(
         fn, mesh=mesh, in_specs=(spec,) * num_args, out_specs=spec
     )
 
@@ -248,31 +242,11 @@ def shard_apps_rows(fn: Callable, mesh: Mesh, radius: int,
         ys = ys.reshape(n, -1, band + 2 * r, W)[:, :, r:r + band, :]
         return ys.reshape(n, ys.shape[1], band * W)
 
-    sharded = _shard_map_impl()(
+    return _shard_map(
         banded, mesh=mesh,
         in_specs=(P(app_axis), P(app_axis), P(app_axis, row_axis)),
         out_specs=P(app_axis, None, row_axis),
     )
-    replicated = NamedSharding(mesh, P())
-
-    def constrained(configs, ingests, images):
-        # Partitioner workaround (jax 0.4.37): resharding an operand that
-        # the compiler left device-sharded into a *partially replicated*
-        # 2-D-mesh in_spec (settings banks ride P(app), replicated over
-        # the rows axis) miscompiles into a sum over the unnamed axis --
-        # padded settings arrive doubled per row shard.  Pinning the
-        # banks fully replicated first makes the boundary reshard a plain
-        # local slice; the banks are KB-scale settings, so replication is
-        # the intended layout anyway (every row shard needs its app's
-        # whole config).  Frames are fully specified by their in_spec and
-        # unaffected.
-        configs, ingests = jax.tree_util.tree_map(
-            lambda a: jax.lax.with_sharding_constraint(a, replicated),
-            (configs, ingests),
-        )
-        return sharded(configs, ingests, images)
-
-    return constrained
 
 
 def shard_pipeline_rows(stage_fn, mesh: Mesh, radii,
@@ -330,25 +304,11 @@ def shard_pipeline_rows(stage_fn, mesh: Mesh, radii,
                 x = jnp.where(valid, y, 0)
         return ys.reshape(n, ys.shape[1], band * W)
 
-    sharded = _shard_map_impl()(
+    return _shard_map(
         banded, mesh=mesh,
         in_specs=(P(app_axis), P(app_axis), P(app_axis, row_axis)),
         out_specs=P(app_axis, None, row_axis),
     )
-    replicated = NamedSharding(mesh, P())
-
-    def constrained(stage_settings, hw, images):
-        # Same jax-0.4.37 partitioner workaround as shard_apps_rows: pin
-        # the KB-scale settings banks (incl. hw) fully replicated so the
-        # boundary reshard into the partially-replicated in_spec is a
-        # plain local slice, not a miscompiled cross-row sum.
-        stage_settings, hw = jax.tree_util.tree_map(
-            lambda a: jax.lax.with_sharding_constraint(a, replicated),
-            (stage_settings, hw),
-        )
-        return sharded(stage_settings, hw, images)
-
-    return constrained
 
 
 def constrain_time_mixer(x):

@@ -5,8 +5,8 @@ from repro.runtime.fault_tolerance import (
 from repro.runtime.fleet import FleetRequest, FleetStats, LRUCache, PixieFleet
 from repro.runtime.resilience import (
     BreakerBoard, CircuitBreaker, DispatchError, JobTimeout,
-    PoisonedOutputError, QuarantinedError, RetryPolicy, ServiceError,
-    TransientError,
+    PlanBuildError, PoisonedOutputError, QuarantinedError, RetryPolicy,
+    ServiceError, TransientError,
 )
 
 __all__ = [
@@ -15,5 +15,5 @@ __all__ = [
     "FaultInjector", "FaultSpec", "InjectedFault",
     "BreakerBoard", "CircuitBreaker", "RetryPolicy",
     "ServiceError", "DispatchError", "QuarantinedError", "JobTimeout",
-    "PoisonedOutputError", "TransientError",
+    "PlanBuildError", "PoisonedOutputError", "TransientError",
 ]

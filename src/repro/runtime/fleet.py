@@ -80,7 +80,8 @@ from repro.parallel.axes import APP_AXIS, ROW_AXIS, MeshSpec, build_mesh
 from repro.runtime.chaos import FaultInjector
 from repro.runtime.fault_tolerance import HeartbeatMonitor
 from repro.runtime.resilience import (
-    BreakerBoard, PoisonedOutputError, QuarantinedError, RetryPolicy,
+    BreakerBoard, PlanBuildError, PoisonedOutputError, QuarantinedError,
+    RetryPolicy,
 )
 
 
@@ -166,6 +167,10 @@ class FleetStats:
     mesh_requested: Tuple[int, int] = (1, 1)
     mesh_granted: Tuple[int, int] = (1, 1)
     mesh_degraded: bool = False
+    # Devices the most recent dispatch's output actually spans (its
+    # sharding's device set): the placement mesh_granted promises, as
+    # observed on the result.
+    output_devices: int = 0
     # Host-side packing time that ran while a previous dispatch was still
     # executing on device (async ingest only): the double-buffer overlap
     # the sync path cannot have.  Completion is observed through
@@ -385,11 +390,15 @@ class PixieFleet:
         self.max_retained_results = int(max_retained_results)
         self._next_ticket = 0
         # -- resilience (PR 10) ----------------------------------------------
-        # Dispatch is ALWAYS resilient: transient failures retry with a
-        # deterministic backoff, a persistently failing plan degrades down
-        # its fallback chain behind a per-plan-key circuit breaker, and a
-        # request no plan can serve is isolated by bisection and fails
-        # ONLY its own ticket (stored in _failures, raised by result()).
+        # Transient failures retry with a deterministic backoff, a
+        # persistently failing plan degrades down its fallback chain
+        # behind a per-plan-key circuit breaker, and a request no plan can
+        # serve is isolated by bisection and fails ONLY its own ticket
+        # (stored in _failures, raised by result()).  One exception: on
+        # an UNARMED fleet (no faults=, breakers= or heartbeat=) a primary
+        # plan that fails to build or lower fails the batch with
+        # PlanBuildError instead of degrading -- a kernel the device
+        # compiler refuses must surface, not be served by the XLA oracle.
         # The policy objects are pure host control flow -- on the happy
         # path they cost a dict lookup per flush group.
         self.faults = faults
@@ -397,11 +406,11 @@ class PixieFleet:
         self.breakers = breakers or BreakerBoard()
         # Flush wall times feed the seed HeartbeatMonitor; a flagged
         # straggler flush counts as a breaker failure for every plan it
-        # dispatched -- but only when the caller opted into chaos/breaker
-        # tuning (faults= or breakers=), so CI noise can never degrade a
-        # vanilla fleet's plans.
+        # dispatched -- but only on an armed fleet (the caller opted into
+        # chaos/breaker tuning), so CI noise can never degrade a vanilla
+        # fleet's plans.
         self.heartbeat = heartbeat if heartbeat is not None else HeartbeatMonitor()
-        self._straggler_trips_breaker = (
+        self._armed = (
             faults is not None or breakers is not None or heartbeat is not None
         )
         # NaN/Inf output guard (inexact dtypes only -- integer fabrics
@@ -512,7 +521,12 @@ class PixieFleet:
             # so the spec keeps firing until exhausted -- exactly like a
             # real deterministic compile error.
             self.faults.fire("compile", (f"plan:{plan.key()}",))
-        fn = compile_plan(plan)
+        try:
+            fn = compile_plan(plan)
+        except Exception as exc:  # noqa: BLE001 -- unarmed: PlanBuildError on every ticket; armed: retry/fallback ladder
+            if self._armed:
+                raise
+            raise PlanBuildError(plan.key(), exc) from exc
         self.stats.overlay_builds += 1
         for evicted in self._overlays.put(plan, fn):
             self.stats.evicted_plans.append(evicted.key())
@@ -910,8 +924,8 @@ class PixieFleet:
             for i, (_, p) in enumerate(items):
                 H, W = p.hw
                 entry.buf[i, :H, :W] = p.payload
-            # copy=True by API contract (plain device_put MAY zero-copy
-            # aligned numpy on CPU in some jax versions, which would let
+            # copy=True by API contract (device_put of aligned numpy may
+            # alias the host buffer on the CPU backend, which would let
             # the pooled buffer's next fill(0) race still-unforced lazy
             # outputs); the pending record defers the transfer wait to
             # the buffer's reuse two flushes later.
@@ -929,8 +943,9 @@ class PixieFleet:
         self.timings["pack_s"] += time.perf_counter() - t0
         t0 = time.perf_counter()
         self._pre_dispatch(plan, items)
-        ys = fn(stacked, ingests, frames)
+        ys = self._execute(fn, stacked, ingests, frames)
         ys = self._corrupt_outputs(plan, items, ys)
+        self.stats.output_devices = len(ys.sharding.device_set)
         self.stats.dispatches += 1
         self.stats.fused_dispatches += 1
         self.stats.stamp_dispatch(fn.plan, f"n{n_tile}x{Hb}x{Wb}")
@@ -1018,8 +1033,9 @@ class PixieFleet:
         self.timings["pack_s"] += time.perf_counter() - t0
         t0 = time.perf_counter()
         self._pre_dispatch(plan, items)
-        ys = fn(stage_settings, hw, frames)
+        ys = self._execute(fn, stage_settings, hw, frames)
         ys = self._corrupt_outputs(plan, items, ys)
+        self.stats.output_devices = len(ys.sharding.device_set)
         self.stats.dispatches += 1
         self.stats.fused_dispatches += 1
         self.stats.pipeline_dispatches += 1
@@ -1068,8 +1084,9 @@ class PixieFleet:
 
         t0 = time.perf_counter()
         self._pre_dispatch(plan, items)
-        ys = fn(stacked, xstack)
+        ys = self._execute(fn, stacked, xstack)
         ys = self._corrupt_outputs(plan, items, ys)
+        self.stats.output_devices = len(ys.sharding.device_set)
         self.stats.dispatches += 1
         self.stats.stamp_dispatch(fn.plan, f"n{n_tile}xb{batch}")
         self.stats.executed += n
@@ -1090,6 +1107,38 @@ class PixieFleet:
                     y = y[0] if y.shape[0] == 1 else y
                 out[ticket] = y
         self.timings["dispatch_s"] += time.perf_counter() - t0
+
+    def _execute(self, fn: OverlayExecutable, *args):
+        """Run one dispatch executable.  On an unarmed fleet a failure
+        that the executable also raises when it is only lowered and
+        compiled for these operands' shapes is a build failure: it is
+        re-raised as :class:`PlanBuildError` (never degraded, see
+        :meth:`_dispatch_resilient`).  A failure that compiles cleanly is
+        a runtime failure and keeps the ladder.  The re-lowering runs on
+        the failure path only."""
+        try:
+            return fn(*args)
+        except Exception as exc:  # noqa: BLE001 -- build failure: PlanBuildError on every ticket; else the retry/fallback ladder
+            if self._armed:
+                raise
+            specs = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
+                args,
+            )
+            try:
+                fn.lower(*specs).compile()
+            except Exception:  # noqa: BLE001 -- fails again at build time: PlanBuildError on every ticket
+                raise PlanBuildError(fn.plan.key(), exc) from exc
+            raise
+
+    def _fail_batch(self, items: List[Tuple[int, _Prepared]],
+                    exc: BaseException) -> None:
+        """Fail every ticket of a work set with the same typed error."""
+        for ticket, _ in items:
+            self._failures[ticket] = exc
+        while len(self._failures) > self.max_retained_results:
+            self._failures.popitem(last=False)
 
     # -- resilient dispatch (PR 10) -------------------------------------------
 
@@ -1219,6 +1268,10 @@ class PixieFleet:
            request(s), whose tickets fail with QuarantinedError while all
            survivors dispatch normally.
 
+        On an unarmed fleet a primary that fails to build or lower
+        (:class:`PlanBuildError`) skips all of this: every ticket of the
+        work set fails with that error and no fallback plan runs.
+
         Breaker successes are deferred to flush end (_settle_flush): a
         straggler flush converts them into breaker failures when the
         fleet is armed for it."""
@@ -1241,6 +1294,15 @@ class PixieFleet:
                 try:
                     self._dispatch_plan(cand, kind, items, got)
                     bad = self._guard_outputs(got, items)
+                except PlanBuildError as exc:
+                    if ci == 0:
+                        # Unarmed fleet, primary refused at build time:
+                        # surface it on the whole batch, never degrade.
+                        self._fail_batch(items, exc)
+                        return
+                    last_exc = exc
+                    br.record_failure()
+                    break
                 except Exception as exc:  # noqa: BLE001 -- routed: retried here, then degraded down the fallback chain or quarantined to the offending ticket below
                     last_exc = exc
                     br.record_failure()
@@ -1286,7 +1348,7 @@ class PixieFleet:
             straggler = self.heartbeat.record(self.stats.dispatches, flush_s)
             if straggler:
                 self.stats.straggler_flushes += 1
-        punish = straggler and self._straggler_trips_breaker
+        punish = straggler and self._armed
         for br, _key in self._flush_successes:
             if punish:
                 br.record_failure("straggler")
@@ -1311,7 +1373,7 @@ class PixieFleet:
         coupling, same as passing ``faults=`` at construction."""
         self.faults = faults
         self._guard = True
-        self._straggler_trips_breaker = True
+        self._armed = True
 
     def cancel_pending(self) -> int:
         """Drop every submitted-but-unflushed request (no results, no

@@ -71,6 +71,22 @@ class QuarantinedError(DispatchError):
         )
 
 
+class PlanBuildError(DispatchError):
+    """A fleet's primary plan failed to *build or lower* for a batch: the
+    executor could not be constructed, traced or compiled for the batch's
+    operands (e.g. the TPU compiler refused a kernel).  An unarmed fleet
+    fails every ticket of the batch with this instead of serving it from
+    a fallback plan, so a device path that cannot compile is never hidden
+    behind the XLA oracle.  Carries the plan key and the cause."""
+
+    def __init__(self, plan_key: str, cause: BaseException):
+        self.plan_key = plan_key
+        self.cause = cause
+        super().__init__(
+            f"plan {plan_key} failed to build or lower: {cause!r}"
+        )
+
+
 class JobTimeout(ServiceError, TimeoutError):
     """A JobHandle.result(timeout=) expired, or a request blew its
     per-request hard timeout while queued.  Subclasses TimeoutError so

@@ -2,7 +2,8 @@ from repro.serve.engine import ServeConfig, ServeEngine, SlotServer
 from repro.serve.fleet_frontend import FleetFrontend
 from repro.serve.service import (
     AdmissionError, DispatchError, ImageJob, ImageService, JobHandle,
-    JobTimeout, LatencyStats, QuarantinedError, ServiceError,
+    JobTimeout, LatencyStats, PlanBuildError, QuarantinedError,
+    ServiceError,
 )
 from repro.serve.streaming import StreamingFrontend
 
@@ -12,4 +13,5 @@ __all__ = [
     "ImageService", "ImageJob", "JobHandle",
     "LatencyStats", "AdmissionError",
     "ServiceError", "DispatchError", "QuarantinedError", "JobTimeout",
+    "PlanBuildError",
 ]

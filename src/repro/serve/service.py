@@ -43,7 +43,8 @@ from repro.core import applications as app_lib
 from repro.core.dfg import DFG
 from repro.core.grid import GridSpec
 from repro.runtime.resilience import (  # noqa: F401  (re-exported surface)
-    DispatchError, JobTimeout, QuarantinedError, ServiceError,
+    DispatchError, JobTimeout, PlanBuildError, QuarantinedError,
+    ServiceError,
 )
 
 

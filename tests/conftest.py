@@ -11,20 +11,6 @@ os.environ.setdefault("JAX_ENABLE_X64", "0")
 # Test tiers (registered in pyproject.toml [tool.pytest.ini_options]):
 #   tier-1 (CI gate, < 5 min):  pytest            (addopts apply -m "not slow")
 #   full / nightly:             pytest -m ""      (marker filter disabled)
-#   TPU-only:                   pytest -m tpu     (skipped off-TPU below)
-
-
-def pytest_collection_modifyitems(config, items):
-    tpu_items = [item for item in items if "tpu" in item.keywords]
-    if not tpu_items:
-        return  # don't pay jax backend init when nothing is tpu-marked
-    import jax
-
-    if any(d.platform == "tpu" for d in jax.devices()):
-        return
-    skip_tpu = pytest.mark.skip(reason="requires a TPU device")
-    for item in tpu_items:
-        item.add_marker(skip_tpu)
 
 
 @pytest.fixture(scope="session")

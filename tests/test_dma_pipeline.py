@@ -18,41 +18,35 @@ tile t+1 while tile t computes.  This suite pins the contract:
   of the EXISTING ``tile_rows`` plan axis -- same plan keys and hashes,
   no new axis, so every PR 5-era cache entry stays valid and repeat
   dispatches hit the fleet's overlay LRU;
-* the lane-alignment rounding lives in ``tiling.resolve_tile_rows``
-  (one definition with the heuristic and the XLA twin);
+* the compiled path's tile-height alignment (whole sublane tiles) lives
+  in ``tiling.resolve_tile_rows`` (one definition with the heuristic and
+  the XLA twin);
 * per-device canvas pooling for sharded async fleets (the PR 5 pointer
   satellite): devices=2 async flushes fill and ship one pooled buffer
   per mesh device, counted in ``FleetStats.canvas_pool_device_hits``,
-  bitwise-equal to the single-device sync run;
-* a ``tpu``-marked compiled perf/parity test (auto-skipped off-TPU):
-  the compiled kernel must match the XLA twin bitwise and the measured
-  pallas/xla fused-e2e ratio is reported against a loose floor.
+  bitwise-equal to the single-device sync run.
+
+The compiled kernel is checked against the TPU compiler in
+test_tpu_compile.py and bitwise against the XLA fleet on the chip by
+chip_smoke.py.
 """
 
 import re
-import time
 from pathlib import Path
 
-import jax
 import numpy as np
 import pytest
 
-from repro.core import OverlayPlan, compile_plan, sobel_grid
-from repro.core import interpreter
+from repro.core import OverlayPlan, sobel_grid
 from repro.core.tiling import (
-    LANE,
+    SUBLANE,
     TILE_AUTO,
-    lane_aligned_tile_rows,
+    aligned_tile_rows,
     resolve_tile_rows,
 )
-from repro.kernels.vcgra.ops import _batched_fused_pallas_fn
 from repro.runtime.fleet import FleetRequest, PixieFleet
 
-from test_tiling import (
-    assert_tiled_equals_untiled,
-    needs_two_devices,
-    random_fused_workload,
-)
+from test_tiling import assert_tiled_equals_untiled, needs_two_devices
 
 GRID = sobel_grid()
 REPO = Path(__file__).resolve().parent.parent
@@ -105,20 +99,20 @@ def test_dma_path_reuses_tile_rows_plan_entries():
 
 def test_lane_alignment_is_resolved_in_tiling():
     """One rounding definition: an AUTO pick that actually tiles, asked
-    with ``lane_align=LANE``, satisfies the compiled kernel's layout
-    constraint and equals ``lane_aligned_tile_rows`` of the unaligned
-    pick -- and the interpret path (lane_align=None) is untouched."""
+    with ``align=SUBLANE``, is whole sublane tiles (the compiled kernel's
+    block rule) and equals ``aligned_tile_rows`` of the unaligned pick --
+    and the interpret path (align=None) is untouched."""
     H, W = 4096, 1920
     raw = resolve_tile_rows(TILE_AUTO, H, W, 1, GRID)
-    aligned = resolve_tile_rows(TILE_AUTO, H, W, 1, GRID, lane_align=LANE)
-    assert 1 <= aligned < H and (aligned * W) % LANE == 0
-    assert aligned == lane_aligned_tile_rows(raw, W)
+    aligned = resolve_tile_rows(TILE_AUTO, H, W, 1, GRID, align=SUBLANE)
+    assert 1 <= aligned < H and aligned % SUBLANE == 0
+    assert aligned == aligned_tile_rows(raw, SUBLANE)
     assert aligned <= raw
     # degenerate-untiled AUTO picks are not rounded (single slab == whole
-    # frame needs no tiling machinery, and H*W is the caller's canvas)
-    assert resolve_tile_rows(TILE_AUTO, 32, 32, 1, GRID, lane_align=LANE) == 32
+    # frame needs no tiling machinery; the kernel pads it to whole tiles)
+    assert resolve_tile_rows(TILE_AUTO, 32, 32, 1, GRID, align=SUBLANE) == 32
     # explicit tile heights are never silently rewritten
-    assert resolve_tile_rows(5, 100, 7, 1, GRID, lane_align=LANE) == 5
+    assert resolve_tile_rows(5, 100, 7, 1, GRID, align=SUBLANE) == 5
 
 
 # -- hypothesis sweep: DMA kernel (interpret) vs the untiled XLA oracle --------
@@ -207,38 +201,3 @@ def test_unsharded_fleet_has_no_device_hits(rng):
         fleet.run_many(reqs)
     assert fleet.stats.canvas_pool_device_hits == {}
     assert fleet.stats.canvas_pool_hits >= 1
-
-
-# -- compiled TPU perf/parity (auto-skipped off-TPU) ---------------------------
-
-
-@pytest.mark.tpu
-def test_compiled_dma_megakernel_parity_and_ratio():
-    """On a real TPU: the compiled (interpret=False) DMA megakernel must
-    match the XLA tiled twin bitwise at 256^2 with a lane-aligned tile,
-    and the measured pallas/xla fused-e2e ratio is asserted against a
-    deliberately loose floor (the honest number lands in
-    BENCH_fleet.json via fleet_throughput.py --frames)."""
-    H = W = 256
-    tile_rows = 64                      # (64 * 256) % 128 == 0
-    stacked, ingests, images = random_fused_workload(H, W, 1, 4, seed=3)
-    xla_fn = jax.jit(lambda s, i, x: interpreter.tiled_batched_fused_overlay_step(
-        GRID, 1, tile_rows, s, i, x))
-    dma_fn = jax.jit(_batched_fused_pallas_fn(GRID, 1, interpret=False,
-                                              tile_rows=tile_rows))
-    ref = np.asarray(xla_fn(stacked, ingests, images))
-    got = np.asarray(dma_fn(stacked, ingests, images))
-    np.testing.assert_array_equal(got, ref)
-
-    def bench(fn):
-        fn(stacked, ingests, images).block_until_ready()     # warm
-        t0 = time.perf_counter()
-        for _ in range(10):
-            y = fn(stacked, ingests, images)
-        y.block_until_ready()
-        return (time.perf_counter() - t0) / 10
-
-    ratio = bench(xla_fn) / bench(dma_fn)   # >1 means pallas is faster
-    # Loose floor: the compiled DMA pipeline must not be catastrophically
-    # slower than the XLA lowering on the hardware it was built for.
-    assert ratio > 0.25, f"compiled pallas/xla fused-e2e ratio {ratio:.2f}"

@@ -31,8 +31,8 @@ from repro.runtime.resilience import (
     BreakerBoard, CircuitBreaker, RetryPolicy, TransientError,
 )
 from repro.serve import (
-    DispatchError, FleetFrontend, JobTimeout, QuarantinedError,
-    StreamingFrontend,
+    DispatchError, FleetFrontend, JobTimeout, PlanBuildError,
+    QuarantinedError, StreamingFrontend,
 )
 
 WAIT = 120.0       # generous per-call bound; loaded CI hosts compile slowly
@@ -394,6 +394,51 @@ def test_compile_fault_falls_back_and_does_not_cache_failure(rng):
     # pallas primary cleanly and serves from it.
     fleet.run_many([FleetRequest(app="sobel_x", image=img)])
     assert fleet.stats.fallback_dispatches == 1
+
+
+# -- fleet: unarmed build failures surface -------------------------------------
+
+
+def _refuse(*args, **kwargs):
+    raise NotImplementedError("kernel refused by the device compiler")
+
+
+@pytest.mark.parametrize("stage", ["build", "lower"])
+def test_unarmed_fleet_surfaces_plan_build_failure(rng, monkeypatch, stage):
+    # An unarmed pallas fleet whose primary plan cannot be built (the
+    # executor builder raises) or lowered (tracing the kernel raises)
+    # fails EVERY ticket of the batch with the typed error; the XLA
+    # fallback never serves it.
+    import repro.kernels.vcgra.ops as kernel_ops
+
+    target = ("_batched_fused_pallas_fn" if stage == "build"
+              else "vcgra_fused_batched")
+    monkeypatch.setattr(kernel_ops, target, _refuse)
+    fleet = _fleet("pallas")
+    tickets = [fleet.submit(FleetRequest(app=n, image=_img(rng)))
+               for n in ("sobel_x", "threshold")]
+    fleet.flush()
+    for t in tickets:
+        with pytest.raises(PlanBuildError) as ei:
+            fleet.result(t)
+        assert isinstance(ei.value, DispatchError)
+        assert isinstance(ei.value.cause, NotImplementedError)
+        assert "|pallas|" in ei.value.plan_key
+    assert fleet.stats.fallback_dispatches == 0
+    assert fleet.stats.quarantined_requests == 0
+    assert fleet.stats.dispatches == 0
+
+
+def test_unarmed_build_failure_reaches_every_handle(rng, monkeypatch):
+    import repro.kernels.vcgra.ops as kernel_ops
+
+    monkeypatch.setattr(kernel_ops, "vcgra_fused_batched", _refuse)
+    svc = FleetFrontend(fleet=_fleet("pallas"))
+    handles = [svc.submit(n, _img(rng)) for n in ("sobel_x", "laplace")]
+    for h in handles:
+        with pytest.raises(PlanBuildError):
+            h.result(timeout=WAIT)
+    assert svc.stats.fallback_dispatches == 0
 
 
 # -- sync front-end routing ---------------------------------------------------

@@ -113,12 +113,14 @@ def test_resolve_tile_rows_and_budget_heuristic():
     assert 1 <= auto_1080 < 1080
     # the working set the pick implies respects the budget, INCLUDING both
     # in-flight DMA slabs of the double buffer (+2 rows per output row plus
-    # the constant 2 * 2r * W halo rows)
+    # the constant 2 * 2r * W halo rows), the double-buffered output block
+    # and the block-sized channel/level buffers of the datapath
     itemsize = jnp.dtype(GRID.dtype).itemsize
-    taps = (2 * 1 + 1) ** 2 + 1
-    per_row = (taps + GRID.num_inputs + max(GRID.pes_per_level) + 2) * 1920 * itemsize
-    halo = 2 * (2 * 1) * 1920 * itemsize
-    assert auto_1080 * per_row + halo <= DEFAULT_VMEM_BUDGET_BYTES
+    taps = (2 * 1 + 1) ** 2
+    per_row = (taps + 2 + 2 * GRID.num_outputs) * 1920 * itemsize
+    fixed = (2 * (2 * 1) * 1920 + (GRID.num_inputs
+             + 2 * max(GRID.pes_per_level)) * 8 * 512) * itemsize
+    assert auto_1080 * per_row + fixed <= DEFAULT_VMEM_BUDGET_BYTES
     # budget monotonicity + floor of one row
     assert slab_rows_per_budget(1 << 20, 2, num_inputs=64, max_level_width=32,
                                 itemsize=4) == 1
