@@ -4,8 +4,8 @@ a CPU rehearsal.
 Run with no arguments, the driver re-executes itself as two coordinated
 worker processes (``--process-id 0|1``), each given two forced host
 devices on the CPU backend (``JAX_PLATFORMS=cpu`` in their environment,
-so on a TPU host neither child claims the chips), and checks the multi-process story end to end as far as the CPU
-backend permits:
+so on a TPU host neither child claims the chips), and checks the
+multi-process story end to end as far as the CPU backend permits:
 
   1. ``jax.distributed.initialize`` handshake: both workers join one
      coordinator and each sees the OTHER's devices in the global world
@@ -22,10 +22,10 @@ backend permits:
      silently pretend to the global shape.
 
 Cross-process collectives themselves are NOT exercised: XLA:CPU raises
-``Multiprocess computations aren't implemented on the CPU backend``,
-so a CPU CI can validate the
-handshake, the world assembly, and the per-host shard math, while the
-collective seam exchange across hosts needs a real TPU/GPU runner.
+``Multiprocess computations aren't implemented on the CPU backend``, so
+a CPU CI can validate the handshake, the world assembly, and the
+per-host shard math, while the collective seam exchange across hosts
+needs a real TPU/GPU runner.
 Exits 0 on success, 1 with the failing worker's log on any mismatch.
 """
 

@@ -7,19 +7,23 @@ main-path kernels with ``interpret=False`` against a described
 ``v5e:2x2`` topology at real canvas widths (1080p frames land on a
 2048^2 canvas, 4K frames on 4096^2) with the AUTO tile height, so a
 change the chip would refuse fails here.  Nothing runs: results are
-checked bitwise on the chip by ``chip_smoke.py``.
+checked bitwise on the chip by ``chip_smoke.py``.  One more lowering
+checks that a kernel's persistent-cache key names no checkout path.
 
 The topology is described inside a module fixture (never at import
 time): only one process may load the TPU library, and describing it
 loads it.  Keep every such compile in this one file.
 """
 
+import base64
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.compile_cache import CHECKOUT, SOURCE_PREFIX_REGEX
 from repro.core import sobel_grid
 from repro.core.tiling import TILE_AUTO
 from repro.kernels.vcgra.ops import (
@@ -103,3 +107,32 @@ def test_channel_kernel_compiles(one_chip):
     fn = _batched_pallas_fn(GRID, interpret=False)
     _compile(fn, _configs(one_chip),
              _spec(one_chip, (N, GRID.num_inputs, 2048 * 2048), GRID.dtype))
+
+
+def _mosaic_payload(sharding, source_regex):
+    """The serialized Mosaic module of the fused kernel, lowered with
+    ``source_regex`` as the source-file canonicalization rule."""
+    was = jax.config.jax_hlo_source_file_canonicalization_regex
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      source_regex)
+    try:
+        fn = _batched_fused_pallas_fn(GRID, 1, interpret=False,
+                                      tile_rows=TILE_AUTO)
+        text = jax.jit(fn).lower(
+            _configs(sharding), _ingests(sharding),
+            _spec(sharding, (N, 256, 256), GRID.dtype)).as_text()
+    finally:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", was)
+    body = re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    return base64.b64decode(body.group(1))
+
+
+def test_kernel_cache_key_names_no_checkout_path(one_chip):
+    """JAX hashes a kernel's Mosaic payload with its source locations into
+    the persistent-cache key; under the entry points' rule those name no
+    absolute checkout path, so another checkout of the same code hits."""
+    checkout = str(CHECKOUT).encode()
+    assert checkout in _mosaic_payload(one_chip, None)
+    payload = _mosaic_payload(one_chip, SOURCE_PREFIX_REGEX)
+    assert b"src/repro/kernels/vcgra/vcgra_kernel.py" in payload
+    assert checkout not in payload
