@@ -11,17 +11,22 @@ from ``--seed``) spread over four library apps and the depth-3 chain
 with ``ingest="async"``.  gauss3 needs 19 memory inputs and the Sobel
 grid has 18, so the chain requests name the grid the library generates
 for gauss3 (``for_dfg(gauss3, shape="rect")``), which hosts all three
-stages.  Every output is compared bit for bit with the
-same requests served by a ``backend="xla"`` fleet in this process, on
-the same chip.  The run fails unless every dispatch ran a pallas plan
+stages.  Then a pallas ``PixieFleet`` serves small ragged frames and
+chains, one flush per canvas the fleet buckets them into (16x32 up to
+256x512), so the lane padding and every block width of the kernels'
+layout run.  Every output is compared bit for bit with the same
+requests served by a ``backend="xla"`` fleet in this process, on the
+same chip.  The run fails unless every dispatch ran a pallas plan
 compiled for the chip (``default_interpret()`` is False), no dispatch
 fell back to another plan, no request was quarantined and no circuit
 breaker moved.
 
 ``--chips 4`` runs only the mesh path and what it is compared with: a
 ``PixieFleet(mesh=MeshSpec(app=2, rows=2), backend="pallas")`` on
-3840x2160 frames, whose output must span the four devices, checked bit
-for bit against the same requests on one chip without a mesh.
+3840x2160 frames, single-stage apps and the chain (the row-banded
+single-stage and chain executors), whose output must span the four
+devices, checked bit for bit against the same requests on one chip
+without a mesh.
 
 Everything runs in this one process (a chip belongs to one process).
 Earlier lines report the devices, the compile seconds of each phase, the
@@ -48,6 +53,14 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 APPS = ("sobel_x", "sobel_y", "laplace", "threshold")
 CHAIN = ("gauss3", "sobel_x", "threshold")
 RESULT_TIMEOUT_S = 900.0
+#: Small frames, one flush per canvas the fleet buckets them into (min
+#: side 16, powers of two): 16x32, 64x128, 128x256 and 256x512.
+SMALL_FLUSHES = (
+    ((5, 30), (16, 9)),
+    ((37, 90), (20, 70)),
+    ((100, 200), (57, 250)),
+    ((200, 300), (150, 480)),
+)
 
 
 class CompileClock:
@@ -153,9 +166,37 @@ def compare(label: str, got, want, reference: str = "the XLA fleet") -> None:
           f"{len(got)}/{len(want)} outputs")
 
 
+def small_frames_phase(clock, *, seed: int) -> None:
+    """Small ragged frames, each flush with a single-stage app and a
+    chain: pallas fleet vs XLA fleet."""
+    from repro.core import sobel_grid
+    from repro.runtime.fleet import PixieFleet
+
+    dtype = sobel_grid().dtype
+    rng = np.random.default_rng(seed)
+    pallas, xla = PixieFleet(backend="pallas"), PixieFleet(backend="xla")
+    label = "pallas small frames"
+    t0 = time.perf_counter()
+    n = 0
+    for k, (app_hw, chain_hw) in enumerate(SMALL_FLUSHES):
+        requests = [(APPS[k % len(APPS)],
+                     rng.integers(0, 256, app_hw).astype(dtype)),
+                    (list(CHAIN), rng.integers(0, 256, chain_hw).astype(dtype))]
+        got = [np.asarray(o) for o in
+               pallas.run_many([to_request(w, im) for w, im in requests])]
+        want = [np.asarray(o) for o in
+                xla.run_many([to_request(w, im) for w, im in requests])]
+        compare(f"{label} {app_hw} + chain {chain_hw}", got, want)
+        n += len(requests)
+    print(f"[{label}] served {n} requests in {time.perf_counter() - t0:.1f} "
+          f"s, compile {clock.lap():.1f} s")
+    check_stats(label, pallas.stats, n)
+
+
 def single_chip_phase(clock, *, seed: int, n_requests: int, height: int,
                       width: int, oracle_tile: int) -> None:
-    """Streaming pallas serving under both ingest modes vs the XLA fleet."""
+    """Streaming pallas serving under both ingest modes vs the XLA fleet,
+    then the small-frame flushes."""
     from repro.core import sobel_grid
     from repro.serve import StreamingFrontend
 
@@ -185,18 +226,23 @@ def single_chip_phase(clock, *, seed: int, n_requests: int, height: int,
               f"{time.perf_counter() - t0:.1f} s, compile {clock.lap():.1f} s")
         check_stats(label, stats, n_requests)
         compare(label, got, want)
+    small_frames_phase(clock, seed=seed + 1)
 
 
-def mesh_phase(clock, *, seed: int, n_requests: int, height: int,
-               width: int) -> None:
-    """The 2x2 (app, rows) mesh fleet vs the same requests on one chip."""
+def mesh_phase(clock, *, seed: int, n_requests: int, n_chains: int,
+               height: int, width: int) -> None:
+    """The 2x2 (app, rows) mesh fleet vs the same requests on one chip:
+    ``n_requests`` single-stage requests and ``n_chains`` chains."""
     from repro.core import MeshSpec, sobel_grid
-    from repro.runtime.fleet import FleetRequest, PixieFleet
+    from repro.runtime.fleet import PixieFleet
 
-    images = frames(seed, n_requests, height, width, sobel_grid().dtype)
-    requests = [(APPS[k % len(APPS)], im) for k, im in enumerate(images)]
-    print(f"[workload] {n_requests} requests of {width}x{height} frames over "
-          f"{', '.join(APPS)}")
+    images = frames(seed, n_requests + n_chains, height, width,
+                    sobel_grid().dtype)
+    requests = [(APPS[k % len(APPS)] if k < n_requests else list(CHAIN), im)
+                for k, im in enumerate(images)]
+    print(f"[workload] {n_requests + n_chains} requests of {width}x{height} "
+          f"frames: {n_requests} over {', '.join(APPS)}, {n_chains} chains "
+          f"{'->'.join(CHAIN)}")
     fleet = PixieFleet(backend="pallas", mesh=MeshSpec(app=2, rows=2),
                        ingest="async", batch_tile=2)
     if fleet.stats.mesh_degraded or fleet.stats.mesh_granted != (2, 2):
@@ -206,21 +252,27 @@ def mesh_phase(clock, *, seed: int, n_requests: int, height: int,
     label = "pallas mesh 2x2"
     got = None
     for flush in range(2):
-        t0 = time.perf_counter()
-        outs = fleet.run_many([FleetRequest(app=w, image=im)
-                               for w, im in requests])
-        outs = [np.asarray(o) for o in outs]
-        print(f"[{label}] flush {flush}: {len(outs)} requests in "
-              f"{time.perf_counter() - t0:.1f} s, compile {clock.lap():.1f} s")
+        outs = []
+        # Single-stage requests and chains flush apart, so the output
+        # placement of each executor is read.
+        for part in (requests[:n_requests], requests[n_requests:]):
+            t0 = time.perf_counter()
+            outs += [np.asarray(o) for o in
+                     fleet.run_many([to_request(w, im) for w, im in part])]
+            print(f"[{label}] flush {flush}: {len(part)} requests in "
+                  f"{time.perf_counter() - t0:.1f} s, compile "
+                  f"{clock.lap():.1f} s, output over "
+                  f"{fleet.stats.output_devices} devices")
+            if fleet.stats.output_devices != 4:
+                raise AssertionError(
+                    f"mesh output spans {fleet.stats.output_devices} "
+                    f"devices, not 4")
         if got is not None:
             compare(f"{label} flush {flush}", outs, got, "flush 0")
         got = outs
-    check_stats(label, fleet.stats, 2 * n_requests)
-    if fleet.stats.output_devices != 4:
-        raise AssertionError(
-            f"mesh output spans {fleet.stats.output_devices} devices, not 4")
+    check_stats(label, fleet.stats, 2 * len(requests))
     t0 = time.perf_counter()
-    want = serve_oracle(requests, 2)
+    want = serve_oracle(requests, 1)
     print(f"[xla oracle, one chip] served in {time.perf_counter() - t0:.1f} s, "
           f"compile {clock.lap():.1f} s")
     compare(label, got, want)
@@ -265,8 +317,8 @@ def main(argv=None) -> int:
     clock = CompileClock(jax)
     try:
         if args.chips == 4:
-            mesh_phase(clock, seed=args.seed, n_requests=4, height=2160,
-                       width=3840)
+            mesh_phase(clock, seed=args.seed, n_requests=4, n_chains=2,
+                       height=2160, width=3840)
         else:
             single_chip_phase(clock, seed=args.seed, n_requests=40,
                               height=1080, width=1920, oracle_tile=4)

@@ -121,14 +121,13 @@ def resolve_tile_rows(
     ``tile_rows >= H`` degenerates to the untiled single-slab layout, so
     small frames pay no tiling machinery under the auto default.
 
-    ``align`` (the compiled megakernels pass :data:`SUBLANE`; the XLA
-    twin and interpret mode pass None -- no layout constraint there)
-    rounds an AUTO pick that actually tiles down to whole sublane tiles
-    via :func:`aligned_tile_rows`, so the heuristic, the XLA tiled twin
-    and the compiled DMA path all resolve through this ONE definition and
-    the kernel's loud alignment assert fires with the already-rounded
-    value.  Explicit int tile heights are the caller's choice and are
-    never silently rewritten.
+    ``align`` (the Pallas megakernels pass :data:`SUBLANE`; the XLA
+    twin passes None -- no layout constraint there) rounds a pick that
+    actually tiles, AUTO or explicit, to whole sublane tiles via
+    :func:`aligned_tile_rows`: the kernels' block rule, so the heuristic,
+    the XLA tiled twin and the DMA path all resolve through this ONE
+    definition.  Tiling is exact, so the rounding changes the working
+    set, never the output.
     """
     if tile_rows is None:
         return max(int(H), 1)
@@ -141,11 +140,12 @@ def resolve_tile_rows(
             num_outputs=grid.num_outputs,
             budget_bytes=budget_bytes,
         )
-        picked = max(1, min(picked, int(H)))
-        if align and picked < int(H):
-            picked = min(aligned_tile_rows(picked, align), int(H))
-        return picked
-    return max(1, min(int(tile_rows), int(H)))
+    else:
+        picked = int(tile_rows)
+    picked = max(1, min(picked, int(H)))
+    if align and picked < int(H):
+        picked = min(aligned_tile_rows(picked, align), int(H))
+    return picked
 
 
 def num_row_tiles(H: int, tile_rows: int) -> int:

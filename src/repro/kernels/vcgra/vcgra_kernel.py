@@ -266,24 +266,18 @@ def vcgra_batched(
     ``x``: [N, num_inputs, B]; returns [N, num_outputs, B].  The pixel axis
     is zero-padded to whole blocks and folded to ``[N, C, B/LANE, LANE]``
     so every value is a 2-D sublane x lane tile; the padding is sliced
-    back off.  ``block_n`` (pixels per block, a LANE multiple) defaults
-    to one sublane tile of lanes; the compiled path needs it to cover
-    whole sublane tiles (a multiple of ``SUBLANE * LANE``) or the whole
-    padded axis.
+    back off.  ``block_n`` (pixels per block, a LANE multiple, default
+    one sublane tile of lanes) is rounded up to whole sublane tiles
+    (``SUBLANE * LANE`` pixels) or the whole padded axis, whichever is
+    smaller -- the TPU compiler's block rule.
     """
     interpret = _resolve_interpret(interpret)
     n_apps, n_in, b = x.shape
-    if block_n is None:
-        br = min(-(-b // LANE), SUBLANE)
-    else:
-        assert block_n % LANE == 0, f"block_n must be lane-aligned (x{LANE})"
-        br = block_n // LANE
-    n_rows = _round_up(-(-b // LANE), br)
-    assert interpret or br % SUBLANE == 0 or br == n_rows, (
-        f"compiled channel kernel needs whole sublane tiles per block: "
-        f"block_n={br * LANE} is not a multiple of {SUBLANE * LANE} and "
-        f"does not cover the padded batch ({n_rows * LANE})"
-    )
+    assert block_n is None or block_n % LANE == 0, (
+        f"block_n must be lane-aligned (x{LANE})")
+    lane_rows = -(-b // LANE)
+    br = min(_round_up((block_n or LANE) // LANE, SUBLANE), lane_rows)
+    n_rows = _round_up(lane_rows, br)
     xp = jnp.pad(x, ((0, 0), (0, 0), (0, n_rows * LANE - b)))
     xp = xp.reshape(n_apps, n_in, n_rows, LANE)
     K = grid.num_outputs
@@ -369,17 +363,14 @@ def _form_bank(radius: int, rows: int, x_ref, bank_ref) -> None:
         bank_ref[t, pl.ds(0, rows), :] = v
 
 
-def _blocks(rows: int, W: int, align: int):
+def _blocks(W: int):
     """The ``[row, lane]`` pixel block the datapath loop walks: one
-    sublane tile by up to 512 lanes on the compiled path (a few vregs per
-    value, so the PE datapath stays in registers), the whole region in
-    interpret mode."""
-    if align == 1:
-        return rows, W
+    sublane tile by up to 512 lanes (a few vregs per value, so the PE
+    datapath stays in registers)."""
     return SUBLANE, next(c for c in (512, 256, LANE) if W % c == 0)
 
 
-def _datapath(grid: GridSpec, radius: int, rows: int, W: int, align: int,
+def _datapath(grid: GridSpec, radius: int, rows: int, W: int,
               idx: Tuple, refs, store) -> None:
     """Channel production + PE pipeline + output store over a ``[rows,
     W]`` region whose tap bank is already formed, block by block.
@@ -391,12 +382,7 @@ def _datapath(grid: GridSpec, radius: int, rows: int, W: int, align: int,
     block at row ``r0``, lane ``c0``.
     """
     tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref, lvl_ref = refs
-    rb, cb = _blocks(rows, W, align)
-    if chan_ref.shape[1:] != (rb, cb):
-        # Interpret-mode pipeline stages below the tallest one: view the
-        # block-sized corner of the scratch (compiled blocks are uniform).
-        chan_ref = chan_ref.at[:, pl.ds(0, rb), pl.ds(0, cb)]
-        lvl_ref = lvl_ref.at[:, :, pl.ds(0, rb), pl.ds(0, cb)]
+    rb, cb = _blocks(W)
     n_cb = W // cb
     zero_row = (2 * radius + 1) ** 2
     dtype = chan_ref.dtype
@@ -417,7 +403,7 @@ def _datapath(grid: GridSpec, radius: int, rows: int, W: int, align: int,
 
 
 def _fused_batched_body(grid: GridSpec, radius: int, tile_rows: int,
-                        align: int, shapes, *refs):
+                        shapes, *refs):
     """Fused-ingest megakernel body: one row-haloed slab -> outputs, per
     (app, row-tile) grid step, with the slab streamed HBM->VMEM by an
     in-kernel double-buffered DMA.
@@ -452,7 +438,7 @@ def _fused_batched_body(grid: GridSpec, radius: int, tile_rows: int,
             o_ref[0, k, pl.ds(r0, last.shape[-2]), pl.ds(c0, last.shape[-1])] = (
                 _output(grid, (i,), outsel_ref, last, k))
 
-    _datapath(grid, radius, tile_rows, o_ref.shape[-1], align, (i,),
+    _datapath(grid, radius, tile_rows, o_ref.shape[-1], (i,),
               (tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref,
                lvl_ref), store)
 
@@ -461,32 +447,25 @@ def _round_up(n: int, align: int) -> int:
     return -(-int(n) // align) * align
 
 
-def _frame_layout(H: int, W: int, halo: int, tile_rows, grid: GridSpec,
-                  interpret: bool):
-    """Row alignment, tile height, tile count and the padded output canvas
-    ``(Hp, Wp)`` of one fused megakernel call.
+def _frame_layout(H: int, W: int, halo: int, tile_rows, grid: GridSpec):
+    """Tile height, tile count and the padded output canvas ``(Hp, Wp)``
+    of one fused megakernel call.
 
-    The compiled path works in whole sublane tiles: tile heights, DMA
-    slab heights and every in-kernel row count are multiples of
-    ``SUBLANE`` (a single tile is the frame rounded up), and W is padded
-    to a LANE multiple -- the output block ``(1, K, tile_rows, Wp)``,
-    the HBM window slices, the VMEM ref slices and the lane rotates need
-    all of it.  The zero pad rows and columns read exactly like the frame
-    border and are sliced back off.  Interpret mode has no layout
-    constraint (``align == 1``) and keeps the frame's own shape.
+    The kernels work in whole sublane tiles: tile heights (rounded by
+    ``resolve_tile_rows(align=SUBLANE)``; a single tile is the frame
+    rounded up), DMA slab heights and every in-kernel row count are
+    multiples of ``SUBLANE``, and W is padded to a LANE multiple -- the
+    output block ``(1, K, tile_rows, Wp)``, the HBM window slices, the
+    VMEM ref slices and the lane rotates need all of it.  The zero pad
+    rows and columns read exactly like the frame border and are sliced
+    back off.  Interpret mode runs this same layout, so the CPU parity
+    suites pin the code path the chip compiles.
     """
-    align = 1 if interpret else SUBLANE
-    tr = resolve_tile_rows(tile_rows, H, W, halo, grid,
-                           align=None if interpret else SUBLANE)
+    tr = resolve_tile_rows(tile_rows, H, W, halo, grid, align=SUBLANE)
     n_tiles = num_row_tiles(H, tr)
     if n_tiles == 1:
-        tr = _round_up(tr, align)
-    assert tr % align == 0, (
-        f"compiled megakernel needs whole sublane tiles: tile_rows={tr} "
-        f"is not a multiple of {SUBLANE} and does not cover H={H}; pick "
-        f"another tile_rows or pass interpret=True"
-    )
-    return align, tr, n_tiles, n_tiles * tr, _round_up(W, 1 if interpret else LANE)
+        tr = _round_up(tr, SUBLANE)
+    return tr, n_tiles, n_tiles * tr, _round_up(W, LANE)
 
 
 def _pad_frames(images, top: int, n_tiles: int, tile_rows: int,
@@ -501,11 +480,11 @@ def _pad_frames(images, top: int, n_tiles: int, tile_rows: int,
 
 
 def _fused_scratch(grid: GridSpec, radius: int, slab_rows: int, rows: int,
-                   W: int, align: int, dtype):
+                   W: int, dtype):
     """VMEM scratch of a fused body: the DMA double buffer + semaphores,
     the tap bank of ``rows`` rows, and the block-sized channels and level
     ping-pong buffer."""
-    rb, cb = _blocks(rows, W, align)
+    rb, cb = _blocks(W)
     return [
         pltpu.VMEM((2, slab_rows, W), dtype),
         pltpu.SemaphoreType.DMA((2,)),
@@ -539,8 +518,8 @@ def vcgra_fused_batched(
     Blocking: the pallas grid iterates (app, row-tile) over the ONE
     zero-padded frame stack, which stays in HBM (``memory_space=ANY``) --
     no host-side halo slab tensor is ever materialized.  ``tile_rows``
-    (int, ``tiling.TILE_AUTO`` or None = whole frame) fixes the tile
-    height (see :func:`_frame_layout`); each grid step's ``[tile_rows +
+    (int, ``tiling.TILE_AUTO`` or None = whole frame) sets the tile
+    height, in whole sublane tiles (see :func:`_frame_layout`); each grid step's ``[tile_rows +
     2*radius, Wp]`` halo window is streamed HBM->VMEM by the kernel's own
     double-buffered ``make_async_copy`` pipeline, so tile t+1's window is
     in flight while tile t's PE pipeline executes, each frame row crosses
@@ -561,9 +540,8 @@ def vcgra_fused_batched(
     images = jnp.asarray(images, grid.dtype)
     n_apps, H, W = images.shape
     r = radius
-    align, tr, n_tiles, Hp, Wp = _frame_layout(H, W, r, tile_rows, grid,
-                                               interpret)
-    slab_rows = _round_up(tr + 2 * r, align)
+    tr, n_tiles, Hp, Wp = _frame_layout(H, W, r, tile_rows, grid)
+    slab_rows = _round_up(tr + 2 * r, SUBLANE)
     frames = _pad_frames(images, r, n_tiles, tr, slab_rows, Wp)
     K = grid.num_outputs
     smem, shapes = _smem_operands(jnp.asarray(tap_sel, jnp.int32), *settings,
@@ -575,11 +553,11 @@ def vcgra_fused_batched(
         # owns the HBM->VMEM movement.
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, K, tr, Wp), lambda i, t, *_: (i, 0, t, 0)),
-        scratch_shapes=_fused_scratch(grid, r, slab_rows, tr, Wp, align,
+        scratch_shapes=_fused_scratch(grid, r, slab_rows, tr, Wp,
                                       images.dtype),
     )
     y = pl.pallas_call(
-        functools.partial(_fused_batched_body, grid, radius, tr, align, shapes),
+        functools.partial(_fused_batched_body, grid, radius, tr, shapes),
         out_shape=jax.ShapeDtypeStruct((n_apps, K, Hp, Wp), images.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -598,7 +576,7 @@ def vcgra_fused_batched(
 
 def _pipeline_batched_body(grid: GridSpec, radii: Tuple[int, ...],
                            tile_rows: int, stage_rows: Tuple[int, ...],
-                           align: int, shapes, *refs):
+                           shapes, *refs):
     """Multi-stage trapezoid body: one haloed slab -> final-stage outputs.
 
     The DMA schedule is exactly ``_fused_batched_body``'s double buffer,
@@ -667,7 +645,7 @@ def _pipeline_batched_body(grid: GridSpec, radii: Tuple[int, ...],
                 x_ref[pl.ds(r0, shape[0]), pl.ds(c0, shape[1])] = jnp.where(
                     valid, y, jnp.zeros_like(y))
 
-        _datapath(grid, r, rows, W, align, (si, i),
+        _datapath(grid, r, rows, W, (si, i),
                   (tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref,
                    lvl_ref), store)
         src = x_ref
@@ -697,7 +675,7 @@ def vcgra_pipeline_batched(
     ``images``: [N, H, W].  Returns [N, num_outputs, H*W] in grid dtype.
 
     The frame stack is zero-padded by the chain's TOTAL radius
-    ``R = sum(radii)`` rows (plus the compiled layout's alignment, see
+    ``R = sum(radii)`` rows (plus the layout's sublane alignment, see
     :func:`_frame_layout`) and stays in HBM; each (app, row-tile) step
     DMAs one haloed window into the 2-slot VMEM double buffer and runs
     the whole stage trapezoid on it (see ``_pipeline_batched_body``), so
@@ -710,20 +688,19 @@ def vcgra_pipeline_batched(
     images = jnp.asarray(images, grid.dtype)
     n_apps, H, W = images.shape
     R = sum(radii)
-    align, tr, n_tiles, Hp, Wp = _frame_layout(H, W, R, tile_rows, grid,
-                                               interpret)
+    tr, n_tiles, Hp, Wp = _frame_layout(H, W, R, tile_rows, grid)
     # Rows each stage computes: its trapezoid height tile_rows + 2*reach,
-    # rounded to whole sublane tiles on the compiled path.  Stage 0 reads
+    # rounded to whole sublane tiles.  Stage 0 reads
     # the slab; later stages read x_ref, which holds the previous stage's
     # rows; the bank is sized by stage 0, the tallest.
-    rows = tuple(_round_up(tr + 2 * sum(radii[si + 1:]), align)
+    rows = tuple(_round_up(tr + 2 * sum(radii[si + 1:]), SUBLANE)
                  for si in range(len(radii)))
-    slab_rows = _round_up(max(tr + 2 * R, rows[0] + 2 * radii[0]), align)
+    slab_rows = _round_up(max(tr + 2 * R, rows[0] + 2 * radii[0]), SUBLANE)
     x_rows = max([rows[si] + 2 * radii[si] for si in range(1, len(radii))]
                  + list(rows[:-1]))
     frames = _pad_frames(images, R, n_tiles, tr, slab_rows, Wp)
     K = grid.num_outputs
-    scratch = _fused_scratch(grid, max(radii), slab_rows, rows[0], Wp, align,
+    scratch = _fused_scratch(grid, max(radii), slab_rows, rows[0], Wp,
                              images.dtype)
     scratch.insert(2, pltpu.VMEM((x_rows, Wp), images.dtype))
     smem, shapes = _smem_operands(
@@ -740,7 +717,7 @@ def vcgra_pipeline_batched(
     )
     y = pl.pallas_call(
         functools.partial(_pipeline_batched_body, grid, radii, tr, rows,
-                          align, shapes),
+                          shapes),
         out_shape=jax.ShapeDtypeStruct((n_apps, K, Hp, Wp), images.dtype),
         grid_spec=grid_spec,
         interpret=interpret,

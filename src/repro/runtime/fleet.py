@@ -424,8 +424,12 @@ class PixieFleet:
         self._failures: "OrderedDict[int, BaseException]" = OrderedDict()
         # Per-flush scratch: breakers owed a success at flush end (the
         # success is deferred so a straggler flush can convert it into a
-        # breaker failure), and the memoized fallback chains.
+        # breaker failure), the (plan, operand shapes) an unarmed fleet
+        # already found to compile cleanly after a failure (so the ladder
+        # re-lowers each at most once per flush), and the memoized
+        # fallback chains.
         self._flush_successes: List[Tuple[Any, str]] = []
+        self._flush_compiled: set = set()
         self._chain_cache = LRUCache(64)
         self.stats.breaker_events = self.breakers.events
         # pack_s accumulates host-side input preparation (submit time);
@@ -1115,7 +1119,8 @@ class PixieFleet:
         re-raised as :class:`PlanBuildError` (never degraded, see
         :meth:`_dispatch_resilient`).  A failure that compiles cleanly is
         a runtime failure and keeps the ladder.  The re-lowering runs on
-        the failure path only."""
+        the failure path only, at most once per flush for each plan and
+        operand shapes."""
         try:
             return fn(*args)
         except Exception as exc:  # noqa: BLE001 -- build failure: PlanBuildError on every ticket; else the retry/fallback ladder
@@ -1126,10 +1131,14 @@ class PixieFleet:
                     a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
                 args,
             )
+            verdict = (fn.plan.key(), str(specs))
+            if verdict in self._flush_compiled:
+                raise
             try:
                 fn.lower(*specs).compile()
             except Exception:  # noqa: BLE001 -- fails again at build time: PlanBuildError on every ticket
                 raise PlanBuildError(fn.plan.key(), exc) from exc
+            self._flush_compiled.add(verdict)
             raise
 
     def _fail_batch(self, items: List[Tuple[int, _Prepared]],
@@ -1438,6 +1447,7 @@ class PixieFleet:
         t0 = time.perf_counter()
         self.timings["flush_started"] = t0
         self._flush_successes = []
+        self._flush_compiled = set()
         for key, items in groups.items():
             self._dispatch_resilient(key, items, out)
         flush_s = time.perf_counter() - t0

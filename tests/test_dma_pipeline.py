@@ -18,8 +18,8 @@ tile t+1 while tile t computes.  This suite pins the contract:
   of the EXISTING ``tile_rows`` plan axis -- same plan keys and hashes,
   no new axis, so every PR 5-era cache entry stays valid and repeat
   dispatches hit the fleet's overlay LRU;
-* the compiled path's tile-height alignment (whole sublane tiles) lives
-  in ``tiling.resolve_tile_rows`` (one definition with the heuristic and
+* the kernels' tile-height alignment (whole sublane tiles) lives in
+  ``tiling.resolve_tile_rows`` (one definition with the heuristic and
   the XLA twin);
 * per-device canvas pooling for sharded async fleets (the PR 5 pointer
   satellite): devices=2 async flushes fill and ship one pooled buffer
@@ -34,10 +34,12 @@ chip_smoke.py.
 import re
 from pathlib import Path
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import OverlayPlan, sobel_grid
+from repro.core import OverlayPlan, compile_plan, sobel_grid
+from repro.core.plan import PipelineSpec
 from repro.core.tiling import (
     SUBLANE,
     TILE_AUTO,
@@ -46,6 +48,7 @@ from repro.core.tiling import (
 )
 from repro.runtime.fleet import FleetRequest, PixieFleet
 
+from test_pipeline import GRID as PIPE_GRID, _stage_settings, chain_configs
 from test_tiling import assert_tiled_equals_untiled, needs_two_devices
 
 GRID = sobel_grid()
@@ -111,8 +114,12 @@ def test_lane_alignment_is_resolved_in_tiling():
     # degenerate-untiled AUTO picks are not rounded (single slab == whole
     # frame needs no tiling machinery; the kernel pads it to whole tiles)
     assert resolve_tile_rows(TILE_AUTO, 32, 32, 1, GRID, align=SUBLANE) == 32
-    # explicit tile heights are never silently rewritten
-    assert resolve_tile_rows(5, 100, 7, 1, GRID, align=SUBLANE) == 5
+    # explicit tile heights that tile are rounded the same way for the
+    # kernels, and taken verbatim by the XLA twin (align=None)
+    assert resolve_tile_rows(5, 100, 7, 1, GRID, align=SUBLANE) == SUBLANE
+    assert resolve_tile_rows(20, 100, 7, 1, GRID, align=SUBLANE) == 16
+    assert resolve_tile_rows(5, 6, 7, 1, GRID, align=SUBLANE) == 6  # one tile
+    assert resolve_tile_rows(5, 100, 7, 1, GRID) == 5
 
 
 # -- hypothesis sweep: DMA kernel (interpret) vs the untiled XLA oracle --------
@@ -161,8 +168,31 @@ def test_dma_multi_tile_multi_app_odd_tiles_bitwise():
     several apps x an ODD number of row tiles per app, where a slot
     rotation keyed on the tile index alone (instead of the linearized
     step) desynchronizes the prefetch at every app boundary."""
-    # H=15, tile_rows=5 -> 3 tiles/app; 4 apps -> 12 steps, odd per-app.
-    assert_tiled_equals_untiled(15, 6, 1, 5, n=4, seed=11, backend="pallas")
+    # H=21, tile_rows=8 -> 3 tiles/app; 4 apps -> 12 steps, odd per-app.
+    assert_tiled_equals_untiled(21, 6, 1, 8, n=4, seed=11, backend="pallas")
+
+
+# -- the chain kernel's tiled layout, run in interpret mode --------------------
+
+
+@pytest.mark.parametrize("tile_rows", [None, 8])
+def test_chain_kernel_layout_bitwise_vs_xla(tile_rows, rng):
+    """The chain megakernel's rounded trapezoid rows and padded lanes on
+    a ragged stack wider than one lane block: bitwise equal to the XLA
+    chain executor."""
+    spec = PipelineSpec.chain(chain_configs())
+    hws = [(21, 200), (13, 150)]
+    canvas = np.zeros((2, 21, 200), np.int32)
+    for i, (h, w) in enumerate(hws):
+        canvas[i, :h, :w] = rng.integers(0, 256, (h, w))
+    args = (_stage_settings([spec] * 2),
+            jnp.asarray(np.asarray(hws, np.int32)), jnp.asarray(canvas))
+    want = compile_plan(OverlayPlan(
+        grid=PIPE_GRID, batched=True, pipeline=(spec,) * 2))(*args)
+    got = compile_plan(OverlayPlan(
+        grid=PIPE_GRID, batched=True, pipeline=(spec,) * 2, backend="pallas",
+        tile_rows=tile_rows))(*args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- per-device canvas pool (sharded async fleets) -----------------------------

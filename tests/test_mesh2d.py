@@ -150,9 +150,9 @@ def test_radius_zero_emits_no_collective():
     # and radius > 0 DOES exchange (the negative control)
     mesh = build_mesh(MeshSpec(rows=2))
     if mesh is not None:
-        from repro.parallel.axes import _shard_map_impl
+        from repro.parallel.axes import _shard_map
         from jax.sharding import PartitionSpec as P
-        fn = _shard_map_impl()(
+        fn = _shard_map(
             lambda s: halo_exchange_rows(s, 1, rows=2),
             mesh=mesh, in_specs=P(None, "rows"), out_specs=P(None, "rows"),
         )
@@ -164,12 +164,12 @@ def test_halo_exchange_matches_neighbor_rows():
     """Each shard's halo is literally its neighbours' edge rows (zeros at
     the frame border), i.e. form_tap_bank's zero-pad semantics."""
     from jax.sharding import PartitionSpec as P
-    from repro.parallel.axes import _shard_map_impl
+    from repro.parallel.axes import _shard_map
 
     mesh = build_mesh(MeshSpec(rows=2))
     full = jnp.arange(2 * 8 * 4, dtype=jnp.int32).reshape(2, 8, 4)
     r = 2
-    fn = _shard_map_impl()(
+    fn = _shard_map(
         lambda s: halo_exchange_rows(s, r, rows=2),
         mesh=mesh, in_specs=P(None, "rows"), out_specs=P(None, "rows"),
     )

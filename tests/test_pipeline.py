@@ -221,10 +221,11 @@ def test_executor_parity_mixed_radii_with_zero(backend, rng):
     )
 
 
-@pytest.mark.parametrize("tile_rows", [None, 8, 5])
+@pytest.mark.parametrize("tile_rows", [None, 8, 5, 16])
 def test_pallas_chain_tile_rows_bitwise(tile_rows, rng):
     """The megakernel's trapezoid stage loop is tiling-invariant -- ragged
-    last tiles (5 does not divide 24) included."""
+    last tiles (16 does not divide 24) included; 5 is rounded to a whole
+    sublane tile (8)."""
     cfgs = chain_configs()
     spec = PipelineSpec.chain(cfgs)
     img = rng.integers(0, 256, (24, 16)).astype(np.int32)

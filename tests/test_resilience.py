@@ -429,6 +429,45 @@ def test_unarmed_fleet_surfaces_plan_build_failure(rng, monkeypatch, stage):
     assert fleet.stats.dispatches == 0
 
 
+class _RuntimeFault:
+    """An executable that compiles for any operands but fails when run."""
+
+    plan = OverlayPlan(grid=sobel_grid(), batched=True, fused=True,
+                       backend="pallas")
+
+    def __init__(self):
+        self.lowered = 0
+
+    def __call__(self, *args):
+        raise RuntimeError("device fault")
+
+    def lower(self, *specs):
+        self.lowered += 1
+        return self
+
+    def compile(self):
+        return self
+
+
+def test_unarmed_runtime_failure_is_relowered_once_per_flush(rng):
+    # Classifying a failure as build-or-runtime re-lowers the executable;
+    # the ladder's retries, fallbacks and bisection halves reuse the
+    # verdict for the same plan and operand shapes within one flush.
+    fleet = _fleet("pallas")
+    fn, frames = _RuntimeFault(), _img(rng)[None]
+    for _ in range(3):
+        with pytest.raises(RuntimeError, match="device fault"):
+            fleet._execute(fn, frames)
+    assert fn.lowered == 1
+    with pytest.raises(RuntimeError):
+        fleet._execute(fn, frames[:, :4])        # other shapes: re-lowered
+    assert fn.lowered == 2
+    fleet.flush()                                # a new flush forgets
+    with pytest.raises(RuntimeError):
+        fleet._execute(fn, frames)
+    assert fn.lowered == 3
+
+
 def test_unarmed_build_failure_reaches_every_handle(rng, monkeypatch):
     import repro.kernels.vcgra.ops as kernel_ops
 

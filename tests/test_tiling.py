@@ -227,6 +227,13 @@ def assert_tiled_equals_untiled(H, W, radius, tile_rows, n, seed, backend):
         (6, 11, 1, 6),    # tile_rows == H (single tile, exact)
         (4, 7, 2, 3),     # radius exceeds tile_rows: halo > tile body
         (9, 3, 2, 64),    # tile_rows >> H clamps to untiled
+        # The Pallas layout (interpret mode runs the one the TPU compiles):
+        # whole sublane tiles, lane-padded canvas, 8 x {512,256,128} blocks.
+        (5, 30, 1, None),     # W < LANE: one padded tile
+        (13, 256, 1, 8),      # 256-lane blocks, ragged bottom tile
+        (9, 200, 2, None),    # width padded 200 -> 256 lanes
+        (10, 512, 1, None),   # 512-lane blocks
+        (17, 640, 0, 8),      # 5 x 128-lane blocks, 3 tiles
     ],
 )
 def test_tiled_edge_cases_bitwise(H, W, radius, tile_rows, backend):

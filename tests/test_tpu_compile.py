@@ -5,8 +5,9 @@ compiler refuses: unaligned block shapes, value-level dynamic slices,
 more VMEM than one kernel may use.  These tests lower and compile the
 main-path kernels with ``interpret=False`` against a described
 ``v5e:2x2`` topology at real canvas widths (1080p frames land on a
-2048^2 canvas, 4K frames on 4096^2) with the AUTO tile height, so a
-change the chip would refuse fails here.  Nothing runs: results are
+2048^2 canvas, 4K frames on 4096^2) with the AUTO tile height, and on
+the small canvases the fleet buckets small frames into, so a change the
+chip would refuse fails here.  Nothing runs: results are
 checked bitwise on the chip by ``chip_smoke.py``.  One more lowering
 checks that a kernel's persistent-cache key names no checkout path.
 
@@ -101,6 +102,32 @@ def test_pipeline_megakernel_compiles(one_chip):
                     _spec(one_chip, (N,))) for _ in radii)
     _compile(fn, stages, _spec(one_chip, (N, 2)),
              _spec(one_chip, (N, 2048, 2048), GRID.dtype))
+
+
+@pytest.mark.parametrize("hw,tile_rows", [
+    ((16, 32), None),          # width padded to one lane block
+    ((64, 128), 8),            # 128-lane blocks, 8 tiles
+    ((128, 256), TILE_AUTO),   # 256-lane blocks
+    ((200, 300), 16),          # width padded to 384: 128-lane blocks
+])
+def test_small_canvas_fused_megakernel_compiles(one_chip, hw, tile_rows):
+    """The canvases small frames land on: lane padding and each block
+    width the datapath walks."""
+    fn = _batched_fused_pallas_fn(GRID, 1, interpret=False,
+                                  tile_rows=tile_rows)
+    _compile(fn, _configs(one_chip, n=2), _ingests(one_chip, n=2),
+             _spec(one_chip, (2, *hw), GRID.dtype))
+
+
+def test_small_canvas_pipeline_megakernel_compiles(one_chip):
+    """A chain whose rounded trapezoid rows exceed the tile on a padded
+    canvas."""
+    radii = (1, 2, 0)
+    fn = pallas_pipeline_fn(GRID, radii, tile_rows=8, interpret=False)
+    stages = tuple((_configs(one_chip, n=2), _ingests(one_chip, n=2),
+                    _spec(one_chip, (2,))) for _ in radii)
+    _compile(fn, stages, _spec(one_chip, (2, 2)),
+             _spec(one_chip, (2, 27, 200), GRID.dtype))
 
 
 def test_channel_kernel_compiles(one_chip):
