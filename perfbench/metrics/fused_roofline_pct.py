@@ -1,0 +1,13 @@
+"""Kernel: the fused single-stage megakernel's share of its roofline.
+The roofline time of every single-stage request served in the traced
+window, over the device time of the kernel's ops in the trace."""
+
+#: How the trace names a Pallas kernel today: every megakernel is an op
+#: ``%fn.N = ... custom-call(...), custom_call_target="tpu_custom_call"``,
+#: so the reader reads only windows that served single-stage requests
+#: alone.
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+def read(ctx):
+    return ctx.roofline_pct("fused", lambda name: KERNEL in name)
