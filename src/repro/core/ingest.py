@@ -23,10 +23,8 @@ application mapped on a grid therefore shares one executable, fused or not.
 from __future__ import annotations
 
 import dataclasses
-import threading
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,92 +46,6 @@ def check_ingest(mode: str) -> str:
             f"unknown ingest mode {mode!r}; expected one of {INGEST_MODES}"
         )
     return mode
-
-
-def _trust_is_ready(leaves) -> bool:
-    """Is ``jax.Array.is_ready()`` a truthful completion signal for these
-    arrays?  XLA:CPU's is optimistic -- it reports ready while the
-    async-dispatched computation is still running -- so only non-CPU
-    placements are trusted (and anything that is not a jax array at all,
-    e.g. eager numpy, is trivially ready)."""
-    for leaf in leaves:
-        devices = getattr(leaf, "devices", None)
-        if devices is None:
-            continue
-        try:
-            if any(d.platform == "cpu" for d in devices()):
-                return False
-        except Exception:
-            return False
-    return True
-
-
-class ReadinessProbe:
-    """Truthful zero-timeout readiness check for an in-flight computation.
-
-    ``FleetStats.ingest_overlap_s`` needs to know whether the previous
-    dispatch was *actually* still executing while the next flush packed its
-    inputs.  ``jax.Array.is_ready()`` cannot be trusted for that on every
-    backend (see :func:`_trust_is_ready`), but ``jax.block_until_ready``
-    is truthful everywhere -- so on untrusted platforms the probe parks a
-    daemon watcher thread on the value and flips an event when the real
-    wait returns; :meth:`ready` is then a zero-timeout event check.  On
-    trusted platforms the thread is skipped and ``is_ready`` is consulted
-    directly (no thread churn on the TPU serving path).
-
-    The probe holds a reference to ``value`` until :meth:`ready` first
-    observes completion, mirroring the buffer-pinning behavior of the old
-    optimistic check; callers drop the probe once it reports ready.
-    """
-
-    def __init__(self, value, trust_is_ready: Optional[bool] = None):
-        self._leaves = jax.tree_util.tree_leaves(value)
-        if trust_is_ready is None:
-            trust_is_ready = _trust_is_ready(self._leaves)
-        self._event: Optional[threading.Event] = None
-        if trust_is_ready:
-            return
-        self._event = threading.Event()
-        watcher = threading.Thread(
-            target=self._watch, name="pixie-readiness-probe", daemon=True
-        )
-        watcher.start()
-
-    def _watch(self) -> None:
-        try:
-            jax.block_until_ready(self._leaves)
-        except Exception:
-            # A failed computation is "done" for overlap accounting; the
-            # dispatch path re-raises the real error on its own read.
-            pass
-        self._event.set()
-
-    def ready(self) -> bool:
-        """Zero-timeout truthful poll: has the computation completed?"""
-        if self._event is not None:
-            done = self._event.is_set()
-        else:
-            done = True
-            for leaf in self._leaves:
-                is_ready = getattr(leaf, "is_ready", None)
-                if callable(is_ready) and not is_ready():
-                    done = False
-                    break
-        if done:
-            self._leaves = ()  # release the pinned buffers
-        return done
-
-    def wait(self, timeout: Optional[float] = None) -> bool:
-        """Block (at most ``timeout`` seconds) until completion; returns
-        whether the computation finished within the wait."""
-        if self._event is not None:
-            done = self._event.wait(timeout)
-        else:
-            jax.block_until_ready(self._leaves)
-            done = True
-        if done:
-            self._leaves = ()
-        return done
 
 
 def tap_offsets(radius: int) -> Tuple[Tuple[int, int], ...]:

@@ -809,8 +809,21 @@ def _compile_pipeline(plan: "OverlayPlan") -> "OverlayExecutable":
     if plan.ingest == "async" and jax.default_backend() != "cpu":
         donate = (2,)
         _install_donation_warning_filter()
-    return OverlayExecutable(plan, jax.jit(fn, donate_argnums=donate),
-                             mesh=mesh)
+    return OverlayExecutable(
+        plan, _jit_named(fn, "pixie_pipeline_dispatch", donate), mesh=mesh)
+
+
+def _jit_named(fn: Callable, name: str, donate: Tuple[int, ...]) -> Callable:
+    """``jax.jit(fn)`` under a stable ``name``: the jitted module is
+    ``jit_<name>`` in the compiled text and the device trace, whatever the
+    executor's inner function is called, so a trace tells the fused,
+    pipeline and packed executables apart."""
+
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, donate_argnums=donate)
 
 
 # -- the compile pipeline ------------------------------------------------------
@@ -925,7 +938,8 @@ def compile_plan(plan: OverlayPlan) -> OverlayExecutable:
     if plan.ingest == "async" and jax.default_backend() != "cpu":
         donate = (num_args - 1,)
         _install_donation_warning_filter()
-    return OverlayExecutable(plan, jax.jit(fn, donate_argnums=donate), mesh=mesh)
+    name = "pixie_fused_dispatch" if plan.fused else "pixie_packed_dispatch"
+    return OverlayExecutable(plan, _jit_named(fn, name, donate), mesh=mesh)
 
 
 _DONATION_FILTER_INSTALLED = False

@@ -110,6 +110,7 @@ def vcgra_specialized(
         in_specs=[pl.BlockSpec((n_in, block_n), lambda i: (0, i))],
         out_specs=pl.BlockSpec((grid.num_outputs, block_n), lambda i: (0, i)),
         interpret=interpret,
+        name="vcgra_specialized",
     )(x)
 
 
@@ -298,6 +299,7 @@ def vcgra_batched(
         out_shape=jax.ShapeDtypeStruct((n_apps, K, n_rows, LANE), x.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="vcgra_batched",
     )(*smem, xp)
     return y.reshape(n_apps, K, n_rows * LANE)[:, :, :b]
 
@@ -561,6 +563,7 @@ def vcgra_fused_batched(
         out_shape=jax.ShapeDtypeStruct((n_apps, K, Hp, Wp), images.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="vcgra_fused_batched",
     )(*smem, frames)
     return y[:, :, :H, :W].reshape(n_apps, K, H * W)
 
@@ -721,5 +724,6 @@ def vcgra_pipeline_batched(
         out_shape=jax.ShapeDtypeStruct((n_apps, K, Hp, Wp), images.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="vcgra_pipeline_batched",
     )(*smem, frames)
     return y[:, :, :H, :W].reshape(n_apps, K, H * W)

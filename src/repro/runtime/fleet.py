@@ -37,7 +37,7 @@ Scheduling model:
   ``ingest="async"`` the pipeline double-buffers -- pooled canvases are
   shipped via ``jax.device_put`` into a donated operand and outputs are
   unpacked lazily, so packing of flush k+1 overlaps the device execution
-  of flush k (``FleetStats.ingest_overlap_s`` accounts the overlap);
+  of flush k;
 * mapped configs are cached by DFG structural hash: a repeat tenant costs
   zero place/route work;
 * compiled batched overlays are cached per grid in a small LRU.
@@ -61,6 +61,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import applications as app_lib
 from repro.core import grid as gridlib
@@ -68,7 +69,7 @@ from repro.core import interpreter
 from repro.core.bitstream import VCGRAConfig
 from repro.core.dfg import DFG
 from repro.core.grid import GridSpec
-from repro.core.ingest import IngestPlan, ReadinessProbe, check_ingest
+from repro.core.ingest import IngestPlan, check_ingest
 from repro.core.pixie import map_app
 from repro.core.plan import (
     OverlayExecutable, OverlayPlan, PipelineSpec, compile_plan, fallback_chain,
@@ -171,13 +172,6 @@ class FleetStats:
     # sharding's device set): the placement mesh_granted promises, as
     # observed on the result.
     output_devices: int = 0
-    # Host-side packing time that ran while a previous dispatch was still
-    # executing on device (async ingest only): the double-buffer overlap
-    # the sync path cannot have.  Completion is observed through
-    # core.ingest.ReadinessProbe -- a truthful zero-timeout check even on
-    # XLA:CPU, whose is_ready() is optimistic -- so serving dashboards can
-    # trust this number on every platform.
-    ingest_overlap_s: float = 0.0
     canvas_pool_hits: int = 0    # frame canvases reused instead of allocated
     # Per-device canvas reuse for sharded async fleets: the pool is keyed
     # by mesh device, so each shard's ingest fills (and ships) its own
@@ -346,10 +340,6 @@ class PixieFleet:
         # sizes / frame buckets drift would otherwise pin two full
         # canvases per distinct shape forever.
         self._canvas_pool = LRUCache(8)
-        # Readiness probe on the most recent dispatch output (async):
-        # overlap accounting checks whether it is still in flight when the
-        # next pack starts -- truthfully, even on XLA:CPU.
-        self._inflight: Optional[ReadinessProbe] = None
         # Jitted group unpackers for the async fused path, keyed by the
         # item shapes: ONE lazy dispatch slices every tenant's [H, W]
         # window out of the canvas outputs (per-item eager slicing costs
@@ -432,10 +422,11 @@ class PixieFleet:
         self._flush_compiled: set = set()
         self._chain_cache = LRUCache(64)
         self.stats.breaker_events = self.breakers.events
-        # pack_s accumulates host-side input preparation (submit time);
-        # dispatch_s accumulates time inside overlay executions; flush_s is
-        # the wall time of the most recent flush.
-        self.timings: Dict[str, float] = {"pack_s": 0.0, "dispatch_s": 0.0}
+        # pack_s accumulates host-side input preparation (submit and the
+        # per-dispatch embed, bank and ship); flush_started/flush_s stamp
+        # the most recent flush (see :meth:`flush`).  Where the time inside
+        # a flush goes is read from the ``pixie.*`` profiler spans.
+        self.timings: Dict[str, float] = {"pack_s": 0.0}
 
     @property
     def devices(self) -> int:
@@ -589,7 +580,8 @@ class PixieFleet:
             raise ValueError("exactly one of app= or pipeline= must be given")
         elif (request.inputs is None) == (request.image is None):
             raise ValueError("exactly one of inputs= or image= must be given")
-        prepared = self._prepare(request)
+        with TraceAnnotation("pixie.intake"):
+            prepared = self._prepare(request)
         ticket = self._next_ticket
         self._next_ticket += 1
         self._pending.append((ticket, prepared))
@@ -646,13 +638,13 @@ class PixieFleet:
 
     def _canvas(self, shape: Tuple[int, ...], dtype,
                 device=None) -> _PooledCanvas:
-        """A zeroed frame canvas from the reuse pool (no per-flush numpy
-        allocation in steady state).  Pool depth 2 under async ingest --
-        the double buffer: flush k+1 packs one buffer while flush k's
-        device_put of the other may still be copying; any pending ship is
-        blocked on here, at reuse time, when it is long complete (sync
-        mode materializes outputs before the next flush, so depth 1 and
-        no pending ships).
+        """A frame canvas from the reuse pool (no per-flush numpy
+        allocation in steady state), for the caller to zero and fill.
+        Pool depth 2 under async ingest -- the double buffer: flush k+1
+        packs one buffer while flush k's device_put of the other may
+        still be copying; any pending ship is blocked on here, at reuse
+        time, when it is long complete (sync mode materializes outputs
+        before the next flush, so depth 1 and no pending ships).
 
         ``device`` keys the pool per mesh device for sharded async fleets
         (:meth:`_ship_sharded_frames`): each device's shard rotates its own
@@ -679,16 +671,45 @@ class PixieFleet:
                 self.stats.canvas_pool_device_hits.get(dkey, 0) + 1
             )
         if entry.pending is not None:
-            try:
-                jax.block_until_ready(entry.pending)
-            except RuntimeError:
-                # Donated and already consumed: execution only starts
-                # once its operands materialize, so the transfer out of
-                # this host buffer necessarily completed.
-                pass
+            with TraceAnnotation("pixie.canvas_wait"):
+                try:
+                    jax.block_until_ready(entry.pending)
+                except RuntimeError:
+                    # Donated and already consumed: execution only starts
+                    # once its operands materialize, so the transfer out
+                    # of this host buffer necessarily completed.
+                    pass
             entry.pending = None
-        entry.buf.fill(0)
         return entry
+
+    def _ship_frames(self, mesh, n_tile: int, Hb: int, Wb: int, dtype,
+                     items) -> jnp.ndarray:
+        """The frames of one fused or chained dispatch on the device:
+        embedded top-left into one pooled zero canvas ``[n_tile, Hb, Wb]``
+        on the host and shipped.  Sharded async plans ship per device
+        (:meth:`_ship_sharded_frames`).
+
+        Under async ingest the ship is ``jnp.array(copy=True)`` (a
+        device_put of aligned numpy may alias the host buffer on the CPU
+        backend, which would let the pooled buffer's next fill race
+        still-unforced lazy outputs), and is not waited on: the pending
+        record defers that wait to the buffer's reuse two flushes later."""
+        if self.ingest == "async" and mesh is not None:
+            return self._ship_sharded_frames(mesh, n_tile, Hb, Wb, dtype,
+                                             items)
+        entry = self._canvas((n_tile, Hb, Wb), dtype)
+        with TraceAnnotation("pixie.embed"):
+            entry.buf.fill(0)
+            for i, (_, p) in enumerate(items):
+                H, W = p.hw
+                entry.buf[i, :H, :W] = p.payload
+        with TraceAnnotation("pixie.ship"):
+            if self.ingest == "async":
+                frames = jnp.array(entry.buf, copy=True)
+                entry.pending = frames
+            else:
+                frames = jnp.asarray(entry.buf)
+        return frames
 
     def _ship_sharded_frames(self, mesh, n_tile: int, Hb: int, Wb: int,
                              dtype, items) -> jnp.ndarray:
@@ -725,25 +746,31 @@ class PixieFleet:
         band = Hb // rows_n
         entries = [[self._canvas((shard_n, band, Wb), dtype, device=d)
                     for d in row] for row in grid2d]
-        for i, (_, p) in enumerate(items):
-            H, W = p.hw
-            ai, slot = i // shard_n, i % shard_n
-            for rj in range(rows_n):
-                h = min(H - rj * band, band)
-                if h > 0:
-                    entries[ai][rj].buf[slot, :h, :W] = (
-                        p.payload[rj * band:rj * band + h]
-                    )
+        with TraceAnnotation("pixie.embed"):
+            for row in entries:
+                for e in row:
+                    e.buf.fill(0)
+            for i, (_, p) in enumerate(items):
+                H, W = p.hw
+                ai, slot = i // shard_n, i % shard_n
+                for rj in range(rows_n):
+                    h = min(H - rj * band, band)
+                    if h > 0:
+                        entries[ai][rj].buf[slot, :h, :W] = (
+                            p.payload[rj * band:rj * band + h]
+                        )
         shards = []
-        for ai in range(app_n):
-            for rj in range(rows_n):
-                e, d = entries[ai][rj], grid2d[ai, rj]
-                if d.platform == "cpu":
-                    shard = jax.device_put(jnp.array(e.buf, copy=True), d)
-                else:
-                    shard = jax.device_put(e.buf, d)
-                e.pending = shard
-                shards.append(shard)
+        with TraceAnnotation("pixie.ship"):
+            for ai in range(app_n):
+                for rj in range(rows_n):
+                    e, d = entries[ai][rj], grid2d[ai, rj]
+                    if d.platform == "cpu":
+                        shard = jax.device_put(jnp.array(e.buf, copy=True),
+                                               d)
+                    else:
+                        shard = jax.device_put(e.buf, d)
+                    e.pending = shard
+                    shards.append(shard)
         return jax.make_array_from_single_device_arrays(
             (n_tile, Hb, Wb), frame_sharding(mesh), shards,
         )
@@ -789,20 +816,6 @@ class PixieFleet:
             fn = jax.jit(unpack)
             self._unpack_fns.put(key, fn)
         return fn
-
-    def _note_overlap(self, pack_started: float) -> None:
-        """Credit host-side pack time to ``ingest_overlap_s`` when it ran
-        concurrently with a still-executing previous dispatch -- and drop
-        the in-flight probe once it observes completion, so a past flush's
-        output buffers are not pinned for the sake of a stats probe.  The
-        probe is truthful on every platform (see
-        :class:`repro.core.ingest.ReadinessProbe`)."""
-        if self._inflight is None:
-            return
-        if self._inflight.ready():
-            self._inflight = None
-        else:
-            self.stats.ingest_overlap_s += time.perf_counter() - pack_started
 
     # -- batched execution ----------------------------------------------------
 
@@ -916,55 +929,22 @@ class PixieFleet:
         self.stats.padded_app_slots += n_tile - n
         self.stats.partial_tile_dispatches += 1 if n < n_tile else 0
 
-        stacked, ingests = self._stacked_bank(grid, configs, fused=True)
-        if self.ingest == "async" and fn.mesh is not None:
-            # Sharded async: per-device pooled canvases, shipped shard by
-            # shard and assembled app-sharded (see _ship_sharded_frames).
-            frames = self._ship_sharded_frames(
-                fn.mesh, n_tile, Hb, Wb, grid.dtype, items
-            )
-        elif self.ingest == "async":
-            entry = self._canvas((n_tile, Hb, Wb), grid.dtype)
-            for i, (_, p) in enumerate(items):
-                H, W = p.hw
-                entry.buf[i, :H, :W] = p.payload
-            # copy=True by API contract (device_put of aligned numpy may
-            # alias the host buffer on the CPU backend, which would let
-            # the pooled buffer's next fill(0) race still-unforced lazy
-            # outputs); the pending record defers the transfer wait to
-            # the buffer's reuse two flushes later.
-            frames = jnp.array(entry.buf, copy=True)
-            entry.pending = frames
-        else:
-            entry = self._canvas((n_tile, Hb, Wb), grid.dtype)
-            for i, (_, p) in enumerate(items):
-                H, W = p.hw
-                entry.buf[i, :H, :W] = p.payload
-            frames = jnp.asarray(entry.buf)
+        with TraceAnnotation("pixie.bank"):
+            stacked, ingests = self._stacked_bank(grid, configs, fused=True)
+        frames = self._ship_frames(fn.mesh, n_tile, Hb, Wb, grid.dtype, items)
         # The canvas embed + bank build + ship above are host-side pack
-        # work; only the overlay execution below counts as dispatch.
-        self._note_overlap(t0)
+        # work; the overlay execution below is not.
         self.timings["pack_s"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
         self._pre_dispatch(plan, items)
-        ys = self._execute(fn, stacked, ingests, frames)
+        with TraceAnnotation("pixie.execute"):
+            ys = self._execute(fn, stacked, ingests, frames)
         ys = self._corrupt_outputs(plan, items, ys)
         self.stats.output_devices = len(ys.sharding.device_set)
         self.stats.dispatches += 1
         self.stats.fused_dispatches += 1
         self.stats.stamp_dispatch(fn.plan, f"n{n_tile}x{Hb}x{Wb}")
         self.stats.executed += n
-        if self.ingest == "async":
-            unpack = self._fused_unpack(tuple(p.hw for _, p in items), Hb, Wb)
-            for (ticket, _), y in zip(items, unpack(ys)):
-                out[ticket] = y
-            self._inflight = ReadinessProbe(ys)
-        else:
-            for i, (ticket, p) in enumerate(items):
-                H, W = p.hw
-                y = np.asarray(ys[i]).reshape((-1, Hb, Wb))[:, :H, :W]
-                out[ticket] = y[0] if y.shape[0] == 1 else y
-        self.timings["dispatch_s"] += time.perf_counter() - t0
+        self._unpack_frames(ys, items, Hb, Wb, out)
 
     def _dispatch_pipeline(
         self, plan: OverlayPlan,
@@ -1001,43 +981,26 @@ class PixieFleet:
         self.stats.padded_app_slots += n_tile - n
         self.stats.partial_tile_dispatches += 1 if n < n_tile else 0
 
-        stage_settings = []
-        for si in range(len(radii)):
-            stacked, ingests = self._stacked_bank(
-                grid, [s.stages[si].config for s in specs], fused=True
-            )
-            out_ch = jnp.asarray(
-                [s.stages[si].out_channel for s in specs], jnp.int32
-            )
-            stage_settings.append((stacked, ingests, out_ch))
-        stage_settings = tuple(stage_settings)
-        hw = np.full((n_tile, 2), (Hb, Wb), np.int32)
-        for i, (_, p) in enumerate(items):
-            hw[i] = p.hw
-        hw = jnp.asarray(hw)
-
-        if self.ingest == "async" and fn.mesh is not None:
-            frames = self._ship_sharded_frames(
-                fn.mesh, n_tile, Hb, Wb, grid.dtype, items
-            )
-        elif self.ingest == "async":
-            entry = self._canvas((n_tile, Hb, Wb), grid.dtype)
+        with TraceAnnotation("pixie.bank"):
+            stage_settings = []
+            for si in range(len(radii)):
+                stacked, ingests = self._stacked_bank(
+                    grid, [s.stages[si].config for s in specs], fused=True
+                )
+                out_ch = jnp.asarray(
+                    [s.stages[si].out_channel for s in specs], jnp.int32
+                )
+                stage_settings.append((stacked, ingests, out_ch))
+            stage_settings = tuple(stage_settings)
+            hw = np.full((n_tile, 2), (Hb, Wb), np.int32)
             for i, (_, p) in enumerate(items):
-                H, W = p.hw
-                entry.buf[i, :H, :W] = p.payload
-            frames = jnp.array(entry.buf, copy=True)
-            entry.pending = frames
-        else:
-            entry = self._canvas((n_tile, Hb, Wb), grid.dtype)
-            for i, (_, p) in enumerate(items):
-                H, W = p.hw
-                entry.buf[i, :H, :W] = p.payload
-            frames = jnp.asarray(entry.buf)
-        self._note_overlap(t0)
+                hw[i] = p.hw
+            hw = jnp.asarray(hw)
+        frames = self._ship_frames(fn.mesh, n_tile, Hb, Wb, grid.dtype, items)
         self.timings["pack_s"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
         self._pre_dispatch(plan, items)
-        ys = self._execute(fn, stage_settings, hw, frames)
+        with TraceAnnotation("pixie.execute"):
+            ys = self._execute(fn, stage_settings, hw, frames)
         ys = self._corrupt_outputs(plan, items, ys)
         self.stats.output_devices = len(ys.sharding.device_set)
         self.stats.dispatches += 1
@@ -1045,17 +1008,25 @@ class PixieFleet:
         self.stats.pipeline_dispatches += 1
         self.stats.stamp_dispatch(fn.plan, f"n{n_tile}x{Hb}x{Wb}")
         self.stats.executed += n
-        if self.ingest == "async":
-            unpack = self._fused_unpack(tuple(p.hw for _, p in items), Hb, Wb)
-            for (ticket, _), y in zip(items, unpack(ys)):
-                out[ticket] = y
-            self._inflight = ReadinessProbe(ys)
-        else:
+        self._unpack_frames(ys, items, Hb, Wb, out)
+
+    def _unpack_frames(self, ys, items: List[Tuple[int, _Prepared]],
+                       Hb: int, Wb: int, out: Dict[int, Any]) -> None:
+        """Each item's ``[H, W]`` (or ``[K, H, W]``) window of a fused or
+        chained dispatch's canvas outputs ``ys [n_tile, K, Hb*Wb]``:
+        sliced lazily by one jitted group program under async ingest,
+        read to the host under sync."""
+        with TraceAnnotation("pixie.unpack"):
+            if self.ingest == "async":
+                unpack = self._fused_unpack(tuple(p.hw for _, p in items),
+                                            Hb, Wb)
+                for (ticket, _), y in zip(items, unpack(ys)):
+                    out[ticket] = y
+                return
             for i, (ticket, p) in enumerate(items):
                 H, W = p.hw
                 y = np.asarray(ys[i]).reshape((-1, Hb, Wb))[:, :H, :W]
                 out[ticket] = y[0] if y.shape[0] == 1 else y
-        self.timings["dispatch_s"] += time.perf_counter() - t0
 
     def _dispatch_packed(
         self, plan: OverlayPlan,
@@ -1081,28 +1052,28 @@ class PixieFleet:
         xs += [jnp.zeros_like(xs[0])] * (n_tile - n)
         self.stats.padded_app_slots += n_tile - n
         self.stats.partial_tile_dispatches += 1 if n < n_tile else 0
-        stacked = self._stacked_bank(grid, configs)
+        with TraceAnnotation("pixie.bank"):
+            stacked = self._stacked_bank(grid, configs)
         xstack = jnp.stack(xs)
-        self._note_overlap(t0)
         self.timings["pack_s"] += time.perf_counter() - t0
 
-        t0 = time.perf_counter()
         self._pre_dispatch(plan, items)
-        ys = self._execute(fn, stacked, xstack)
+        with TraceAnnotation("pixie.execute"):
+            ys = self._execute(fn, stacked, xstack)
         ys = self._corrupt_outputs(plan, items, ys)
         self.stats.output_devices = len(ys.sharding.device_set)
         self.stats.dispatches += 1
         self.stats.stamp_dispatch(fn.plan, f"n{n_tile}xb{batch}")
         self.stats.executed += n
-        if self.ingest == "async":
-            unpack = self._packed_unpack(
-                tuple(p.payload.shape[-1] for _, p in items),
-                tuple(p.hw for _, p in items),
-            )
-            for (ticket, _), y in zip(items, unpack(ys)):
-                out[ticket] = y
-            self._inflight = ReadinessProbe(ys)
-        else:
+        with TraceAnnotation("pixie.unpack"):
+            if self.ingest == "async":
+                unpack = self._packed_unpack(
+                    tuple(p.payload.shape[-1] for _, p in items),
+                    tuple(p.hw for _, p in items),
+                )
+                for (ticket, _), y in zip(items, unpack(ys)):
+                    out[ticket] = y
+                return
             for i, (ticket, p) in enumerate(items):
                 y = np.asarray(ys[i, :, : p.payload.shape[-1]])
                 if p.hw is not None:
@@ -1110,7 +1081,6 @@ class PixieFleet:
                     y = y[:, : H * W].reshape((-1, H, W))
                     y = y[0] if y.shape[0] == 1 else y
                 out[ticket] = y
-        self.timings["dispatch_s"] += time.perf_counter() - t0
 
     def _execute(self, fn: OverlayExecutable, *args):
         """Run one dispatch executable.  On an unarmed fleet a failure
@@ -1414,7 +1384,10 @@ class PixieFleet:
         Per-flush latency stamps land in ``timings``: ``flush_started``
         (perf_counter at dispatch start, shared by every request in the
         flush -- front-ends split per-request queue wait from flush time
-        with it) and ``flush_s`` (wall duration of this flush).
+        with it) and ``flush_s`` (wall duration of this flush).  Under
+        async ingest ``flush_s`` ends once the dispatch is *enqueued*, not
+        when the device has served it; the device's part is read from the
+        ``pixie.*`` profiler spans and the device trace.
 
         Returns {ticket: output}; image requests come back as [H, W] (or
         [num_outputs, H, W]), channel requests as [num_outputs, batch].

@@ -266,7 +266,6 @@ class FleetFrontend(ImageService):
 
     @property
     def timings(self):
-        """Fleet timing split: cumulative ``pack_s`` (host-side input prep)
-        vs ``dispatch_s`` (device execution) plus last ``flush_s`` /
-        ``flush_started``."""
+        """Fleet timings: cumulative ``pack_s`` (host-side input prep)
+        plus the last flush's ``flush_started`` / ``flush_s``."""
         return self.fleet.timings

@@ -70,10 +70,17 @@ class ImageJob:
 
     ``queue_s`` is the wait from submit until its flush *started*;
     ``flush_s`` is the wall duration of the flush that served it (shared
-    by every job in that flush); ``latency_s`` is the end-to-end total.
-    The old single ``latency_s``-stamped-after-flush conflated the two --
-    every job in a batch inherited the full flush time inside its queue
-    wait -- so schedulers could not tell queueing delay from execution.
+    by every job in that flush); ``latency_s`` is the total from submit
+    until the flush returned.  The old single
+    ``latency_s``-stamped-after-flush conflated the two -- every job in a
+    batch inherited the full flush time inside its queue wait -- so
+    schedulers could not tell queueing delay from execution.
+
+    Under async ingest ``flush_s`` and ``latency_s`` end when the flush
+    has been *enqueued*, not served: the output is a lazy device array
+    that the device may not have computed yet.  The device part of a
+    request's latency is read from the ``pixie.*`` profiler spans and the
+    device trace, not from these fields.
     """
 
     ticket: int
@@ -170,7 +177,11 @@ class LatencyStats:
 
     Per-request samples are split three ways (see :class:`ImageJob`):
     ``queue_s`` (submit -> flush start), ``flush_s`` (flush duration) and
-    ``total_s`` (submit -> served).  Samples live in bounded deques (a
+    ``total_s`` (submit -> flush end).  Under async ingest the flush ends
+    once it is enqueued, before the device has served it, so ``flush_s``
+    and ``total_s`` leave out the device's part (read that from the
+    ``pixie.*`` profiler spans and the device trace).  Deadline misses are
+    judged on ``total_s`` all the same.  Samples live in bounded deques (a
     long-running server must not grow without bound) while the SLO
     counters -- ``completed``, ``deadline_misses``, ``with_deadline``,
     ``shed`` -- are cumulative.  Thread-safe: the streaming worker records

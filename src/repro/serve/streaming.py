@@ -59,6 +59,7 @@ import time
 from typing import Dict, List, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import applications as app_lib
 from repro.core.dfg import DFG
@@ -420,7 +421,8 @@ class StreamingFrontend(ImageService):
             # without blocking.
             timeout = self._wake_in(pending)
             try:
-                item = self._queue.get(timeout=timeout)
+                with TraceAnnotation("pixie.wait_arrivals"):
+                    item = self._queue.get(timeout=timeout)
                 if item is _STOP:
                     self._stopping = True
                 else:
@@ -534,9 +536,17 @@ class StreamingFrontend(ImageService):
         return batch
 
     def _dispatch(self, batch: List[_PendingRequest]) -> None:
-        """One fleet flush for the selected batch.  Per-request fleet
-        submit failures (unmappable app, grid mismatch) fail only their
-        own handle -- they can never poison the rest of the batch."""
+        """One fleet flush for the selected batch, traced as the span
+        ``pixie.flush`` (args: the flush's ``seq`` and the ``n`` requests
+        selected)."""
+        with TraceAnnotation("pixie.flush", seq=self._flush_seq,
+                             n=len(batch)):
+            self._flush_batch(batch)
+
+    def _flush_batch(self, batch: List[_PendingRequest]) -> None:
+        """Per-request fleet submit failures (unmappable app, grid
+        mismatch) fail only their own handle -- they can never poison the
+        rest of the batch."""
         tickets: Dict[int, _PendingRequest] = {}
         for p in batch:
             try:

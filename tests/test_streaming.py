@@ -21,7 +21,6 @@ import pytest
 
 from repro.core import applications as apps
 from repro.core import sobel_grid
-from repro.core.ingest import ReadinessProbe
 from repro.runtime.fleet import PixieFleet
 from repro.serve import (
     AdmissionError, FleetFrontend, JobHandle, StreamingFrontend,
@@ -349,49 +348,6 @@ def test_streaming_matches_sync_256(backend, rng):
                 for n, i in trace]
     for a, b in zip(ref, outs):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# -- truthful readiness probe -------------------------------------------------
-
-
-def test_readiness_probe_completes():
-    x = jnp.arange(4096) * 2
-    p = ReadinessProbe(x)
-    assert p.wait(timeout=30.0)
-    assert p.ready()
-
-
-def test_readiness_probe_trusted_path_skips_thread():
-    x = jnp.arange(16)
-    jnp.asarray(x).block_until_ready()
-    p = ReadinessProbe(x, trust_is_ready=True)
-    assert p._event is None                   # no watcher thread spawned
-    assert p.ready()
-
-
-def test_readiness_probe_untrusted_on_cpu():
-    """On CPU the probe must NOT take jax's optimistic is_ready at its
-    word: a watcher thread provides the truthful signal."""
-    if jnp.zeros(1).devices() and all(
-        d.platform == "cpu" for d in jnp.zeros(1).devices()
-    ):
-        p = ReadinessProbe(jnp.arange(16))
-        assert p._event is not None           # watcher thread in play
-        assert p.wait(timeout=30.0)
-
-
-def test_probe_overlap_accounting_async_fleet(rng):
-    """The async fleet's ingest_overlap_s rides the truthful probe and
-    stays a finite, non-negative number across repeated flushes."""
-    from repro.runtime.fleet import FleetRequest
-    img = rng.integers(0, 256, (16, 16)).astype(np.int32)
-    fleet = PixieFleet(default_grid=sobel_grid(), ingest="async")
-    reqs = [FleetRequest(app=n, image=img) for n in ["sobel_x", "sharpen"]]
-    for _ in range(4):
-        fleet.run_many(reqs)
-    assert fleet.stats.ingest_overlap_s >= 0.0
-    assert np.isfinite(fleet.stats.ingest_overlap_s)
-    assert fleet.stats.canvas_pool_hits >= 1
 
 
 def test_urgent_request_preempts_staged_batch(rng):

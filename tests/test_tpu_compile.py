@@ -25,8 +25,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.compile_cache import CHECKOUT, SOURCE_PREFIX_REGEX
-from repro.core import sobel_grid
+from repro.core import applications, map_app, sobel_grid
+from repro.core.plan import OverlayPlan, PipelineSpec, compile_plan
 from repro.core.tiling import TILE_AUTO
+from repro.kernels.vcgra import vcgra_kernel
 from repro.kernels.vcgra.ops import (
     _batched_fused_pallas_fn,
     _batched_pallas_fn,
@@ -132,8 +134,41 @@ def test_small_canvas_pipeline_megakernel_compiles(one_chip):
 
 def test_channel_kernel_compiles(one_chip):
     fn = _batched_pallas_fn(GRID, interpret=False)
-    _compile(fn, _configs(one_chip),
-             _spec(one_chip, (N, GRID.num_inputs, 2048 * 2048), GRID.dtype))
+    compiled = _compile(fn, _configs(one_chip),
+                        _spec(one_chip, (N, GRID.num_inputs, 2048 * 2048),
+                              GRID.dtype))
+    assert "%vcgra_batched" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["fused", "pipeline"])
+def test_served_plans_name_their_megakernels(one_chip, monkeypatch, kind):
+    """The fleet's async pallas plans compile to a module and a kernel
+    that a device trace tells apart -- ``jit_pixie_<kind>_dispatch``
+    around ``vcgra_<kind>_batched`` -- and the kernel is still a
+    ``tpu_custom_call``, the string the roofline readers match."""
+    monkeypatch.setattr(vcgra_kernel, "default_interpret", lambda: False)
+    n = 2
+    frames = _spec(one_chip, (n, 256, 256), GRID.dtype)
+    if kind == "fused":
+        plan = OverlayPlan(grid=GRID, batched=True, fused=True, radius=1,
+                           backend="pallas", tile_rows=TILE_AUTO,
+                           ingest="async")
+        args = (_configs(one_chip, n=n), _ingests(one_chip, n=n), frames)
+    else:
+        spec = PipelineSpec.chain([
+            map_app(applications.ALL_APPS[a](), GRID)
+            for a in ("sobel_x", "threshold")])
+        plan = OverlayPlan(grid=GRID, batched=True, pipeline=(spec,) * n,
+                           backend="pallas", tile_rows=TILE_AUTO,
+                           ingest="async")
+        args = (tuple((_configs(one_chip, n=n), _ingests(one_chip, n=n),
+                       _spec(one_chip, (n,))) for _ in spec.radii),
+                _spec(one_chip, (n, 2)), frames)
+    text = compile_plan(plan).lower(*args).compile().as_text()
+    assert f"HloModule jit_pixie_{kind}_dispatch," in text
+    kernels = re.findall(r"%(vcgra_\w+)[.\d]* = (.*)", text)
+    assert [name for name, _ in kernels] == [f"vcgra_{kind}_batched"]
+    assert 'custom_call_target="tpu_custom_call"' in kernels[0][1]
 
 
 def _mosaic_payload(sharding, source_regex):
