@@ -14,10 +14,14 @@ semantics, implemented here in two forms:
                        only that functional unit is emitted (the analogue of
                        the paper's parameterized configuration / constant
                        propagation through TLUTs).
-* ``apply_generic`` -- *conventional* form: the opcode is a traced array,
-                       every functional unit is computed and the result is
-                       selected by a mux chain (the analogue of the generic
-                       settings-register-driven PE).
+* ``apply_generic`` -- *conventional* form of the XLA interpreter: the
+                       opcode is a traced per-lane array, every functional
+                       unit is computed and the result is selected by a
+                       mux chain (the analogue of the generic
+                       settings-register-driven PE).  The Pallas kernel
+                       does not use it: its opcode is an SMEM scalar, so
+                       it branches on it and runs ``apply_op`` of the one
+                       configured unit.
 
 Extension opcodes beyond the paper's set (MAX, MIN, ABS) follow the paper's
 note that "the functionality of the processing elements is extendable"; the
@@ -102,13 +106,16 @@ def apply_op(op: Op, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 def apply_generic(opcode: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Conventional PE: every functional unit computed, mux selects output.
+    """Conventional PE of the XLA interpreter: every functional unit
+    computed, mux selects output.
 
     ``opcode`` has shape ``a.shape[:1]`` (one opcode per PE lane) or is a
     scalar; it broadcasts against ``a``/``b`` of shape ``[n_pes, batch]``.
-    This deliberately mirrors the generic hardware PE: all units are live
-    because the settings register is runtime data, exactly why the
-    conventional implementation costs more resources (paper Table I).
+    It mirrors the generic hardware PE, all units live because the
+    settings register is runtime data (paper Table I).  The Pallas
+    conventional kernel holds each opcode as an SMEM scalar instead and
+    branches on it, computing only the configured unit with the same
+    results (``vcgra_kernel._pe_unit``).
     """
     if opcode.ndim == a.ndim - 1:
         opcode = opcode[..., None]

@@ -11,11 +11,14 @@ variants mirror the paper's two implementations:
   analogue and the fast path.
 
 * **conventional**: the settings live in SMEM (scalar-prefetched, the
-  settings-register analogue); every PE evaluates the full functional-unit
-  mux chain and routing is performed with dynamic row selects against the
-  previous level's VMEM value matrix.  Same executable serves every
-  application mapped on the grid -- at the cost the paper's Table I
-  quantifies.
+  settings-register analogue); each PE branches on its scalar opcode and
+  runs only its configured functional unit, and routing is performed
+  with dynamic row selects against the previous level's VMEM value
+  matrix.  Same executable serves every application mapped on the grid.
+  The paper's Table I resource cost of a generic PE is modelled by
+  ``core/specialize.py`` and ``core/synthesis.py``, not by how this kernel
+  spends cycles; the XLA interpreter keeps the per-lane mux form
+  (``ops.apply_generic``), where the opcode is a vector.
 
 Block layout: inputs are stacked channel-major ``[num_inputs, N]`` where N
 is the flattened pixel batch; blocks are ``(num_inputs, block_n)`` with
@@ -167,6 +170,28 @@ def _clamp(idx, size: int):
     return jnp.minimum(jnp.maximum(idx, 0), size - 1)
 
 
+#: The functional units a PE can be configured to, ADD through ABS.
+_UNITS = tuple(Op(k) for k in range(Op.ADD, Op.ABS + 1))
+
+
+def _pe_unit(op, a, b, out_ref) -> None:
+    """One PE: store the unit its SMEM opcode ``op`` names into ``out_ref``.
+
+    The opcode is a scalar, so each unit sits behind its own scalar branch
+    and only the configured one runs; ``apply_op`` holds every unit's
+    semantics.  NONE, MAC and any out-of-range code store zeros, as
+    ``ops.apply_generic`` (the XLA interpreter's per-lane mux) does.
+    """
+    for unit in _UNITS:
+        @pl.when(op == int(unit))
+        def _(unit=unit):
+            out_ref[...] = pe_ops.apply_op(unit, a, b)
+
+    @pl.when(jnp.logical_or(op < int(Op.ADD), op > int(Op.ABS)))
+    def _():
+        out_ref[...] = jnp.zeros_like(a)
+
+
 def _run_levels(grid: GridSpec, idx: Tuple, op_ref, sel_ref, src_ref, lvl_ref):
     """The conventional PE-level pipeline over one pixel block, shared by
     every conventional kernel body.
@@ -175,9 +200,12 @@ def _run_levels(grid: GridSpec, idx: Tuple, op_ref, sel_ref, src_ref, lvl_ref):
     leading app axis, ``(si, i)`` for a stage-stacked one).  ``src_ref``
     holds the memory-VC channels ``[C, rows, lanes]``; ``lvl_ref`` is the
     ``[2, max_w, rows, lanes]`` ping-pong buffer the levels write in
-    turn.  Returns the ref view holding the last level's outputs.  Dense
-    settings are padded to max_w but only the grid's true per-level width
-    is ever read, so pad slots cost nothing.
+    turn.  Each PE branches on its scalar opcode and computes only its
+    configured unit (:func:`_pe_unit`), so a level costs the units its
+    app uses, not every unit of every PE.  Returns the ref view holding
+    the last level's outputs.  Dense settings are padded to max_w but
+    only the grid's true per-level width is ever read, so pad slots cost
+    nothing.
     """
     src, n_src = src_ref, grid.num_inputs
     for lvl in range(grid.num_levels):    # grid structure static, settings not
@@ -186,8 +214,7 @@ def _run_levels(grid: GridSpec, idx: Tuple, op_ref, sel_ref, src_ref, lvl_ref):
         def pe(slot, carry, lvl=lvl, src=src, dst=dst, n_src=n_src):
             a = src[_clamp(sel_ref[idx + (lvl, slot, 0)], n_src)]
             b = src[_clamp(sel_ref[idx + (lvl, slot, 1)], n_src)]
-            op = jnp.full(a.shape, op_ref[idx + (lvl, slot)], jnp.int32)
-            dst[slot] = pe_ops.apply_generic(op, a, b)
+            _pe_unit(op_ref[idx + (lvl, slot)], a, b, dst.at[slot])
             return carry
 
         jax.lax.fori_loop(0, grid.pes_per_level[lvl], pe, 0)

@@ -3,6 +3,8 @@ pure-jnp oracle, swept over applications, shapes and dtypes -- plus the
 batched fused-ingest megakernel (N tenants, raw frames, one pallas_call)
 vs the batched interpreter oracle."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,7 @@ from conftest import shared_app_grid
 from repro.core import for_dfg, map_app, sobel_grid
 from repro.core import applications as apps
 from repro.core.bitstream import VCGRAConfig
+from repro.core.grid import rectangular
 from repro.core.ingest import IngestPlan
 from repro.core.interpreter import (
     batched_fused_overlay_step,
@@ -20,6 +23,7 @@ from repro.core.interpreter import (
     pack_inputs,
     pad_channels,
 )
+from repro.core.ops import Op, apply_generic
 from repro.kernels.vcgra import (
     default_interpret,
     make_batched_fused_pallas_fn,
@@ -29,7 +33,7 @@ from repro.kernels.vcgra import (
     vcgra_apply_image,
     vcgra_ref,
 )
-from repro.kernels.vcgra.vcgra_kernel import _pack_settings
+from repro.kernels.vcgra.vcgra_kernel import _pack_settings, vcgra_batched
 
 
 def _setup(app_name, data_bits=32, float_pe=False, shape="exact"):
@@ -103,6 +107,50 @@ def test_conventional_settings_pack_roundtrip():
         w = grid.pes_per_level[lvl]
         np.testing.assert_array_equal(np.asarray(ops_arr)[lvl, :w], cfg.opcodes[lvl])
         np.testing.assert_array_equal(np.asarray(sel_arr)[lvl, :w], cfg.selects[lvl])
+
+
+# -- PE opcode dispatch ------------------------------------------------------
+
+#: One PE on two memory inputs: the kernel's opcode branch in isolation.
+PE_GRIDS = {
+    "int32": rectangular("one-pe-int", 2, 1, 1),
+    "float32": rectangular("one-pe-float", 2, 1, 1, float_pe=True),
+}
+#: Operand values: zeros (DIV by zero) and negatives (floor division).
+PE_VALUES = {
+    "int32": np.array([-9, -7, -2, -1, 0, 1, 2, 3, 7, 100], np.int32),
+    "float32": np.array([-7.25, -2.0, -1.0, -0.5, -0.0, 0.0, 0.5, 1.0, 3.0,
+                         100.0], np.float32),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _one_pe_kernel(dtype_name):
+    """The conventional kernel jitted once per grid: each opcode is SMEM
+    data, so every case of a grid reuses one executable."""
+    return jax.jit(functools.partial(vcgra_batched, PE_GRIDS[dtype_name],
+                                     interpret=True))
+
+
+@pytest.mark.parametrize("dtype_name", sorted(PE_GRIDS))
+@pytest.mark.parametrize("opcode", [*range(len(Op)), len(Op), -1])
+def test_conventional_kernel_opcode_matches_apply_generic(dtype_name, opcode):
+    """Each opcode -- every unit, NONE, MAC and out-of-range codes -- takes
+    its scalar branch in the kernel and is bitwise equal to the XLA
+    interpreter's per-lane mux on every operand pair."""
+    grid = PE_GRIDS[dtype_name]
+    vals = PE_VALUES[dtype_name]
+    a, b = (v.ravel() for v in np.meshgrid(vals, vals, indexing="ij"))
+    settings = (jnp.full((1, 1, 1), opcode, jnp.int32),
+                jnp.asarray([[[[0, 1]]]], jnp.int32),
+                jnp.zeros((1, 1), jnp.int32))
+    x = jnp.asarray(np.stack([a, b]))[None]
+    got = _one_pe_kernel(dtype_name)(settings, x)
+    want = apply_generic(jnp.asarray(opcode, jnp.int32), jnp.asarray(a),
+                         jnp.asarray(b))
+    got, want = np.asarray(got)[0, 0], np.asarray(want)
+    assert got.dtype == want.dtype == grid.dtype
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
 
 
 # -- batched fused-ingest megakernel ------------------------------------------

@@ -121,6 +121,16 @@ def test_small_canvas_fused_megakernel_compiles(one_chip, hw, tile_rows):
              _spec(one_chip, (2, *hw), GRID.dtype))
 
 
+def test_float_grid_fused_megakernel_compiles(one_chip):
+    """A float32 grid: every PE unit branch, the float DIV among them,
+    compiles for the chip."""
+    grid = sobel_grid(float_pe=True)
+    fn = _batched_fused_pallas_fn(grid, 1, interpret=False, tile_rows=8)
+    _compile(fn, _configs(one_chip, grid, n=2),
+             _ingests(one_chip, grid, n=2),
+             _spec(one_chip, (2, 64, 128), grid.dtype))
+
+
 def test_small_canvas_pipeline_megakernel_compiles(one_chip):
     """A chain whose rounded trapezoid rows exceed the tile on a padded
     canvas."""
