@@ -298,7 +298,7 @@ def make_fused_overlay_fn(grid: GridSpec, radius: int = 1):
 
 def batched_fused_overlay_step(
     grid: GridSpec, radius: int, configs: ConfigArrays,
-    ingests: IngestArrays, images: jnp.ndarray,
+    ingests: IngestArrays, images: jnp.ndarray, hw=None,
 ) -> jnp.ndarray:
     """N apps on N raw frames in ONE dispatch, line buffers included.
 
@@ -306,10 +306,13 @@ def batched_fused_overlay_step(
     (``IngestPlan.stack``), tap_sel [N, C] / const_vals [N, C].  The
     per-app tap selection uses the same flat-bank offset trick as the VC
     muxes in :func:`batched_overlay_step`: one plain gather over a
-    [N*(T+1), pixels] bank, never a batched-indices gather.
+    [N*(T+1), pixels] bank, never a batched-indices gather.  ``hw``
+    (int32 [N, 2] true frame extents, or None for the whole canvas)
+    zeroes the outputs outside each app's extent, as the megakernel does.
     """
     bank = form_tap_bank(images, radius, grid.dtype)
-    return batched_overlay_step(grid, configs, select_channels_batched(bank, ingests))
+    ys = batched_overlay_step(grid, configs, select_channels_batched(bank, ingests))
+    return mask_outputs(ys, hw, *images.shape[1:])
 
 
 def select_channels_batched(bank: jnp.ndarray, ingests: IngestArrays) -> jnp.ndarray:
@@ -327,7 +330,7 @@ def select_channels_batched(bank: jnp.ndarray, ingests: IngestArrays) -> jnp.nda
 
 def tiled_batched_fused_overlay_step(
     grid: GridSpec, radius: int, tile_rows, configs: ConfigArrays,
-    ingests: IngestArrays, images: jnp.ndarray,
+    ingests: IngestArrays, images: jnp.ndarray, hw=None,
 ) -> jnp.ndarray:
     """Row-tiled twin of :func:`batched_fused_overlay_step`: bitwise-equal
     outputs with the tap bank formed per ``[tile_rows + 2*radius, W]``
@@ -358,7 +361,8 @@ def tiled_batched_fused_overlay_step(
     r = radius
     tr = resolve_tile_rows(tile_rows, H, W, r, grid)
     if tr >= H:
-        return batched_fused_overlay_step(grid, radius, configs, ingests, imgs)
+        return batched_fused_overlay_step(grid, radius, configs, ingests, imgs,
+                                          hw)
     T = num_row_tiles(H, tr)
     slabs = halo_row_slabs(imgs, tr, r).reshape(n * T, tr + 2 * r, W)
     bank = form_tap_bank_slab(slabs, radius, grid.dtype)   # [N*T, taps+1, tr*W]
@@ -368,7 +372,7 @@ def tiled_batched_fused_overlay_step(
     # [N*T, K, tr*W] -> per-app tile concat along the pixel axis (row-major
     # flattening makes each tile's pixels contiguous), minus the pad rows.
     y = ys.reshape(n, T, -1, tr * W).swapaxes(1, 2).reshape(n, -1, T * tr * W)
-    return y[:, :, : H * W]
+    return mask_outputs(y[:, :, : H * W], hw, H, W)
 
 
 def valid_pixel_mask(hw: jnp.ndarray, H: int, W: int) -> jnp.ndarray:
@@ -388,18 +392,28 @@ def valid_pixel_mask(hw: jnp.ndarray, H: int, W: int) -> jnp.ndarray:
     return jnp.logical_and(rows_in, cols_in)
 
 
-def forward_stage_output(ys: jnp.ndarray, out_ch: jnp.ndarray,
-                         valid: jnp.ndarray) -> jnp.ndarray:
+def mask_outputs(ys: jnp.ndarray, hw, H: int, W: int) -> jnp.ndarray:
+    """``ys [N, K, H*W]`` with every pixel outside each app's extent
+    ``hw`` set to zero (the executors' output contract); ``hw=None`` is
+    the whole canvas and leaves ``ys`` as it is."""
+    if hw is None:
+        return ys
+    n = ys.shape[0]
+    valid = valid_pixel_mask(hw, H, W).reshape(n, 1, H * W)
+    return jnp.where(valid, ys, jnp.zeros_like(ys))
+
+
+def forward_stage_output(ys: jnp.ndarray, out_ch: jnp.ndarray, H: int,
+                         W: int) -> jnp.ndarray:
     """Select each app's forwarded output channel from a stage's
-    ``[N, K, H*W]`` result and zero it outside the app's true frame
-    region: the inter-stage hop of the operand-settings pipeline chain.
-    ``out_ch`` is int32 ``[N]`` (runtime data, like every other setting);
-    ``valid`` is :func:`valid_pixel_mask`'s ``[N, H, W]``."""
-    n, H, W = valid.shape
+    ``[N, K, H*W]`` result, already zero outside the app's true frame
+    region, as the next stage's ``[N, H, W]`` frames: the inter-stage hop
+    of the operand-settings pipeline chain.  ``out_ch`` is int32 ``[N]``
+    (runtime data, like every other setting)."""
     y = jnp.take_along_axis(
         ys, out_ch.astype(jnp.int32)[:, None, None], axis=1
     )[:, 0]
-    return jnp.where(valid, y.reshape(n, H, W), 0)
+    return y.reshape(-1, H, W)
 
 
 def pipeline_batched_fused_step(
@@ -416,20 +430,20 @@ def pipeline_batched_fused_step(
     (SPMD traces once; per-shard constants are impossible there).  The
     single-device XLA path instead bakes the chain at trace time
     (``plan._pipeline_specialized_fn``); both are bitwise equal to the
-    staged per-stage oracle.  ``stage_fn(radius, configs, ingests, x)``
-    runs one stage (the plan supplies the backend's batched fused step,
-    tiled or not); the last stage returns its full ``[N, K, H*W]`` output
-    -- its ``out_ch`` entry is forwarding metadata with nothing to feed.
+    staged per-stage oracle.  ``stage_fn(radius, configs, ingests, hw,
+    x)`` runs one stage (the plan supplies the backend's batched fused
+    step, tiled or not); the last stage returns its full ``[N, K, H*W]``
+    output, zero outside ``hw`` -- its ``out_ch`` entry is forwarding
+    metadata with nothing to feed.
     """
     x = jnp.asarray(images, grid.dtype)
-    n, H, W = x.shape
-    valid = valid_pixel_mask(hw, H, W)
+    _, H, W = x.shape
     ys = None
     for si, r in enumerate(radii):
         configs, ingests, out_ch = stage_settings[si]
-        ys = stage_fn(r, configs, ingests, x)
+        ys = stage_fn(r, configs, ingests, hw, x)
         if si < len(radii) - 1:
-            x = forward_stage_output(ys, out_ch, valid)
+            x = forward_stage_output(ys, out_ch, H, W)
     return ys
 
 
@@ -438,8 +452,9 @@ def make_batched_fused_overlay_fn(grid: GridSpec, radius: int = 1,
     """Deprecated: use ``compile_plan(OverlayPlan(grid=grid, batched=True,
     fused=True, radius=radius, backend=backend))``.
 
-    Returns ``fn(stacked_configs, stacked_ingests, images) -> ys`` with
-    ``images: [N, H, W] -> ys: [N, num_outputs, H*W]``.  One executable
+    Returns ``fn(stacked_configs, stacked_ingests, hw, images) -> ys`` with
+    ``images: [N, H, W] -> ys: [N, num_outputs, H*W]``, zero outside each
+    app's extent ``hw`` (int32 [N, 2]; None = whole canvas).  One executable
     per (grid, radius, backend, N, H, W); a fleet that pads N and the
     frame canvas to fixed tiles compiles exactly once per grid."""
     from repro.core.plan import OverlayPlan

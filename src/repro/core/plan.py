@@ -518,13 +518,16 @@ class OverlayExecutable:
       batched=False, fused=False   fn(config_arrays, x)
       batched=False, fused=True    fn(config_arrays, ingest_arrays, image)
       batched=True,  fused=False   fn(stacked_configs, xs)
-      batched=True,  fused=True    fn(stacked_configs, stacked_ingests, images)
+      batched=True,  fused=True    fn(stacked_configs, stacked_ingests, hw, images)
       pipeline (depth > 1)         fn(stage_settings, hw, images)
 
-    Pipeline operands: ``stage_settings`` is one ``(stacked_configs,
-    stacked_ingests, out_ch)`` triple per stage (``out_ch`` int32 [N]);
     ``hw`` is int32 [N, 2] of per-app true ``(rows, cols)`` inside the
-    (possibly bucketed) canvas -- everything outside is zeroed between
+    (possibly bucketed) canvas (a batched fused dispatch also takes None:
+    the whole canvas).  Every executor returns zeros outside it, and the
+    pallas megakernels compute nothing there; a padded app slot carries
+    ``(0, 0)``.  Pipeline operands: ``stage_settings`` is one
+    ``(stacked_configs, stacked_ingests, out_ch)`` triple per stage
+    (``out_ch`` int32 [N]); everything outside ``hw`` is zeroed between
     stages so the fused chain matches the staged oracle bitwise.  The
     single-device XLA executor is *specialized at trace time* from the
     plan's static configs and ignores the settings operands (the plan is
@@ -612,11 +615,18 @@ def _xla_batched(plan: OverlayPlan) -> Callable:
 @register_executor("xla", batched=True, fused=True)
 def _xla_batched_fused(plan: OverlayPlan) -> Callable:
     if plan.tile_rows is not None:
-        return partial(
+        step = partial(
             interpreter.tiled_batched_fused_overlay_step,
             plan.grid, plan.radius, plan.tile_rows,
         )
-    return partial(interpreter.batched_fused_overlay_step, plan.grid, plan.radius)
+    else:
+        step = partial(interpreter.batched_fused_overlay_step, plan.grid,
+                       plan.radius)
+
+    def fn(configs, ingests, hw, images):
+        return step(configs, ingests, images, hw)
+
+    return fn
 
 
 # -- pipeline executors --------------------------------------------------------
@@ -705,13 +715,13 @@ def _pipeline_specialized_fn(plan: "OverlayPlan") -> Callable:
                     axis=0,
                 )
                 x = jnp.where(valid, y.reshape(n, H, W), 0)
-        return ys
+        return interpreter.mask_outputs(ys, hw, H, W)
 
     return fn
 
 
 def _pipeline_stage_fn(plan: "OverlayPlan") -> Callable:
-    """Per-stage executor ``stage_fn(radius, configs, ingests, x)`` for the
+    """Per-stage executor ``stage_fn(radius, configs, ingests, hw, x)`` for the
     operand-settings pipeline chain (mesh-sharded paths: SPMD traces once,
     so per-shard trace-time constants are impossible and settings stay
     runtime data, exactly like single-stage sharded dispatch)."""
@@ -720,16 +730,16 @@ def _pipeline_stage_fn(plan: "OverlayPlan") -> Callable:
 
         return pallas_pipeline_stage_fn(plan.grid, plan.tile_rows)
     if plan.tile_rows is not None:
-        def stage(radius, configs, ingests, x):
+        def stage(radius, configs, ingests, hw, x):
             return interpreter.tiled_batched_fused_overlay_step(
-                plan.grid, radius, plan.tile_rows, configs, ingests, x
+                plan.grid, radius, plan.tile_rows, configs, ingests, x, hw
             )
 
         return stage
 
-    def stage(radius, configs, ingests, x):
+    def stage(radius, configs, ingests, hw, x):
         return interpreter.batched_fused_overlay_step(
-            plan.grid, radius, configs, ingests, x
+            plan.grid, radius, configs, ingests, x, hw
         )
 
     return stage
@@ -866,22 +876,22 @@ def _with_mesh_padding(fn: Callable, spec: MeshSpec, radius: int) -> Callable:
     are static under jit: both pad amounts are trace-time constants."""
     app, rows = spec.app, spec.rows
 
-    def padded(configs, ingests, images):
+    def padded(configs, ingests, hw, images):
         n, H, W = images.shape
         pad_n = (-n) % app
         if pad_n:
-            configs, ingests, images = jax.tree_util.tree_map(
+            configs, ingests, hw, images = jax.tree_util.tree_map(
                 lambda a: jnp.concatenate(
                     [a, jnp.broadcast_to(a[-1:], (pad_n,) + a.shape[1:])],
                     axis=0,
                 ),
-                (configs, ingests, images),
+                (configs, ingests, hw, images),
             )
         band = row_band(H, rows, radius)
         pad_h = band * rows - H
         if pad_h:
             images = jnp.pad(images, ((0, 0), (0, pad_h), (0, 0)))
-        ys = fn(configs, ingests, images)
+        ys = fn(configs, ingests, hw, images)
         if pad_h:
             ys = ys.reshape(ys.shape[0], ys.shape[1], band * rows, W)
             ys = ys[:, :, :H, :].reshape(ys.shape[0], ys.shape[1], H * W)
@@ -913,7 +923,7 @@ def compile_plan(plan: OverlayPlan) -> OverlayExecutable:
         raise ValueError(f"no executor registered for plan {plan.key()}")
     fn = builder(plan)
 
-    num_args = 3 if plan.fused else 2
+    num_args = (4 if plan.batched else 3) if plan.fused else 2
     mesh = None
     if plan.mesh.size > 1:
         mesh = build_mesh(plan.mesh)

@@ -89,22 +89,24 @@ def _batched_fused_pallas_fn(grid: GridSpec, radius: int = 1, interpret=None,
 
     Signature twin of the XLA batched fused-ingest plan executors
     (``interpreter.batched_fused_overlay_step`` and its row-tiled twin):
-    ``fn(stacked_configs, stacked_ingests, images) -> ys`` with
-    ``images: [N, H, W] -> ys: [N, num_outputs, H*W]``.  Settings and
-    ingest plans are runtime operands (scalar-prefetched to SMEM), so one
-    executable per (grid, radius, tile_rows, N, H, W) serves every
-    application -- the same compile-once contract as the XLA path,
+    ``fn(stacked_configs, stacked_ingests, hw, images) -> ys`` with
+    ``images: [N, H, W] -> ys: [N, num_outputs, H*W]``, zero outside each
+    app's extent ``hw`` (int32 [N, 2], or None for the whole canvas).
+    Settings, ingest plans and extents are runtime operands
+    (scalar-prefetched to SMEM), so one executable per (grid, radius,
+    tile_rows, N, H, W) serves every application and every frame size in
+    the canvas -- the same compile-once contract as the XLA path,
     bitwise-equal outputs.  ``tile_rows`` (int / ``tiling.TILE_AUTO`` /
     None) selects the pixel-axis row tiling of the kernel grid.
     """
 
-    def fn(stacked_configs, stacked_ingests, images):
+    def fn(stacked_configs, stacked_ingests, hw, images):
         settings = pack_settings_batched(grid, stacked_configs)
         tap_sel, const_vals = stacked_ingests
         return vcgra_fused_batched(
             grid, radius, settings,
             (jnp.asarray(tap_sel, jnp.int32), const_vals),
-            images, interpret=interpret, tile_rows=tile_rows,
+            images, interpret=interpret, tile_rows=tile_rows, hw=hw,
         )
 
     return fn
@@ -152,17 +154,18 @@ def pallas_pipeline_fn(grid: GridSpec, radii, tile_rows=None, interpret=None):
 
 
 def pallas_pipeline_stage_fn(grid: GridSpec, tile_rows=None, interpret=None):
-    """Per-stage pallas executor ``stage_fn(radius, configs, ingests, x)``
-    for the mesh-sharded pipeline chain drivers (``parallel/axes.py``):
-    each stage runs the single-stage fused megakernel on its shard band,
+    """Per-stage pallas executor ``stage_fn(radius, configs, ingests, hw,
+    x)`` for the mesh-sharded pipeline chain drivers (``parallel/axes.py``):
+    each stage runs the single-stage fused megakernel on its shard (``hw``
+    the apps' extents, or None on a row band, whose rows are band-local),
     with the generic driver owning inter-stage halo exchange and masking.
     (Under shard_map the stage loop cannot fold into one kernel -- halo
     rows live on neighbor devices between stages.)"""
 
-    def stage_fn(radius, stacked_configs, stacked_ingests, images):
+    def stage_fn(radius, stacked_configs, stacked_ingests, hw, images):
         return _batched_fused_pallas_fn(
             grid, int(radius), interpret, tile_rows
-        )(stacked_configs, stacked_ingests, images)
+        )(stacked_configs, stacked_ingests, hw, images)
 
     return stage_fn
 
@@ -231,7 +234,7 @@ def _plan_single_fused(plan: OverlayPlan):
 
     def fn(config, ingest, image):
         return batched(_lift_app_axis(config), _lift_app_axis(ingest),
-                       image[None])[0]
+                       None, image[None])[0]
 
     return fn
 

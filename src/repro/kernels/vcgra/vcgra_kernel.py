@@ -34,6 +34,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -228,8 +229,6 @@ def _output(grid: GridSpec, idx: Tuple, outsel_ref, last_ref, k):
 
 
 def _pack_settings(grid: GridSpec, config: VCGRAConfig):
-    import numpy as np
-
     max_w = max(grid.pes_per_level)
     ops_arr = np.zeros((grid.num_levels, max_w), np.int32)
     sel_arr = np.zeros((grid.num_levels, max_w, 2), np.int32)
@@ -431,6 +430,27 @@ def _datapath(grid: GridSpec, radius: int, rows: int, W: int,
     jax.lax.fori_loop(0, (rows // rb) * n_cb, block, 0)
 
 
+def _in_extent(y, row0, col0, h, w):
+    """``y`` (a ``[rows, lanes]`` block whose first pixel sits at canvas
+    row ``row0``, column ``col0``) with every pixel outside the slot's
+    frame extent ``[0, h) x [0, w)`` set to zero."""
+    grow = row0 + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+    gcol = col0 + jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+    valid = jnp.logical_and(jnp.logical_and(grow >= 0, grow < h), gcol < w)
+    return jnp.where(valid, y, jnp.zeros_like(y))
+
+
+def _live_step(hw_ref, tile_rows: int):
+    """Whether grid step ``(i, t)`` computes anything: its output rows
+    ``[t * tile_rows, (t + 1) * tile_rows)`` hold a pixel of slot ``i``'s
+    frame.  A slot of extent ``(0, 0)`` (a padded app slot) has no live
+    step, and the tiles below a frame's last row are dead.
+    :func:`grid_steps` counts the same predicate on the host."""
+    i = pl.program_id(0)
+    t = pl.program_id(1)
+    return jnp.logical_and(t * tile_rows < hw_ref[i, 0], hw_ref[i, 1] > 0)
+
+
 def _fused_batched_body(grid: GridSpec, radius: int, tile_rows: int,
                         shapes, *refs):
     """Fused-ingest megakernel body: one row-haloed slab -> outputs, per
@@ -453,23 +473,39 @@ def _fused_batched_body(grid: GridSpec, radius: int, tile_rows: int,
     without the slab ever leaving VMEM.  The untiled layout is simply
     T == 1: one window covering the whole padded frame, same body, no
     second buffer ever filled.
+
+    Only live steps (:func:`_live_step`) run the tap bank and the
+    datapath, and they store zeros outside the slot's extent ``hw``; a
+    dead step stores a zero block.  Every step still takes its part in
+    the slab stream, so the double buffer rotates as it always does.
     """
-    tap_ref, op_ref, sel_ref, outsel_ref, const_ref = (
+    tap_ref, op_ref, sel_ref, outsel_ref, hw_ref, const_ref = (
         _Smem(r, s) for r, s in zip(refs, shapes))
     (frames_ref, o_ref, slabs_ref, dma_sems_ref,
      bank_ref, chan_ref, lvl_ref) = refs[len(shapes):]
     i = pl.program_id(0)
+    t = pl.program_id(1)
     slot = _slab_dma_pipeline(frames_ref, slabs_ref, dma_sems_ref, tile_rows)
-    _form_bank(radius, tile_rows, slabs_ref.at[slot], bank_ref)
+    live = _live_step(hw_ref, tile_rows)
+    h, w = hw_ref[i, 0], hw_ref[i, 1]
 
-    def store(last, r0, c0):
-        for k in range(grid.num_outputs):
-            o_ref[0, k, pl.ds(r0, last.shape[-2]), pl.ds(c0, last.shape[-1])] = (
-                _output(grid, (i,), outsel_ref, last, k))
+    @pl.when(live)
+    def _():
+        _form_bank(radius, tile_rows, slabs_ref.at[slot], bank_ref)
 
-    _datapath(grid, radius, tile_rows, o_ref.shape[-1], (i,),
-              (tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref,
-               lvl_ref), store)
+        def store(last, r0, c0):
+            for k in range(grid.num_outputs):
+                y = _output(grid, (i,), outsel_ref, last, k)
+                o_ref[0, k, pl.ds(r0, y.shape[-2]), pl.ds(c0, y.shape[-1])] = (
+                    _in_extent(y, t * tile_rows + r0, c0, h, w))
+
+        _datapath(grid, radius, tile_rows, o_ref.shape[-1], (i,),
+                  (tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref,
+                   lvl_ref), store)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
 
 def _round_up(n: int, align: int) -> int:
@@ -495,6 +531,20 @@ def _frame_layout(H: int, W: int, halo: int, tile_rows, grid: GridSpec):
     if n_tiles == 1:
         tr = _round_up(tr, SUBLANE)
     return tr, n_tiles, n_tiles * tr, _round_up(W, LANE)
+
+
+def grid_steps(grid: GridSpec, halo: int, tile_rows, hw, H: int,
+               W: int) -> Tuple[int, int]:
+    """``(steps, live)`` of one fused or pipeline megakernel call over
+    ``[N, H, W]`` canvases whose slots hold frames of extents ``hw``
+    (``[N, 2]``; ``halo`` is the radius, a chain's total radius): the
+    call's ``N * T`` grid steps, and how many of them are live by the
+    kernel's own predicate (:func:`_live_step`), so the host can count
+    what the device skips."""
+    tr, n_tiles, _, _ = _frame_layout(H, W, halo, tile_rows, grid)
+    hw = np.asarray(hw, np.int64).reshape(-1, 2)
+    tiles = np.minimum(-(-np.maximum(hw[:, 0], 0) // tr), n_tiles)
+    return len(hw) * n_tiles, int(np.where(hw[:, 1] > 0, tiles, 0).sum())
 
 
 def _pad_frames(images, top: int, n_tiles: int, tile_rows: int,
@@ -531,6 +581,7 @@ def vcgra_fused_batched(
     images: jnp.ndarray,
     interpret: Optional[bool] = None,
     tile_rows=None,
+    hw: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Batched fused-ingest megakernel: N raw frames, N tenants, ONE
     pallas_call -- the Pallas twin of
@@ -542,7 +593,12 @@ def vcgra_fused_batched(
     in grid dtype), all scalar-prefetched into SMEM; ``images``: [N, H, W],
     cast to the grid dtype at entry exactly like the XLA path's
     ``form_tap_bank`` (so parity holds even for frames arriving in another
-    dtype).  Returns [N, num_outputs, H*W] in the grid dtype.
+    dtype).  ``hw``: int32 [N, 2] true (rows, cols) of each app's frame
+    inside the canvas (None: the whole canvas), scalar-prefetched too.
+    Returns [N, num_outputs, H*W] in the grid dtype, zero outside each
+    app's extent: a row tile below the frame, or every tile of a slot of
+    extent ``(0, 0)``, runs no datapath at all (see
+    :func:`_fused_batched_body`).
 
     Blocking: the pallas grid iterates (app, row-tile) over the ONE
     zero-padded frame stack, which stays in HBM (``memory_space=ANY``) --
@@ -568,13 +624,15 @@ def vcgra_fused_batched(
     tap_sel, const_vals = ingests
     images = jnp.asarray(images, grid.dtype)
     n_apps, H, W = images.shape
+    if hw is None:
+        hw = np.full((n_apps, 2), (H, W), np.int32)
     r = radius
     tr, n_tiles, Hp, Wp = _frame_layout(H, W, r, tile_rows, grid)
     slab_rows = _round_up(tr + 2 * r, SUBLANE)
     frames = _pad_frames(images, r, n_tiles, tr, slab_rows, Wp)
     K = grid.num_outputs
     smem, shapes = _smem_operands(jnp.asarray(tap_sel, jnp.int32), *settings,
-                                  const_vals)
+                                  jnp.asarray(hw, jnp.int32), const_vals)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(smem),
         grid=(n_apps, n_tiles),
@@ -633,9 +691,10 @@ def _pipeline_batched_body(grid: GridSpec, radii: Tuple[int, ...],
     Settings banks carry a leading stage axis (``[S, N, ...]``; the
     ``(si, i)`` SMEM index prefix reuses the shared helpers), so one
     compiled kernel serves every depth-S chain on the grid -- the
-    settings-register contract at chain scale.  The final stage writes
-    straight to the output block, unmasked, like the single-stage kernel
-    (callers slice the canvas).
+    settings-register contract at chain scale.  The final stage stores
+    zeros outside the extent too, and only live steps run the stages at
+    all (:func:`_live_step`; a dead step stores a zero block), exactly as
+    in the single-stage kernel.
     """
     (tap_ref, op_ref, sel_ref, outsel_ref, outch_ref, hw_ref,
      const_ref) = (_Smem(r, s) for r, s in zip(refs, shapes))
@@ -644,41 +703,44 @@ def _pipeline_batched_body(grid: GridSpec, radii: Tuple[int, ...],
     i = pl.program_id(0)
     t = pl.program_id(1)
     slot = _slab_dma_pipeline(frames_ref, slabs_ref, dma_sems_ref, tile_rows)
-    src = slabs_ref.at[slot]             # haloed rows of the whole chain
+    live = _live_step(hw_ref, tile_rows)
+    h, w = hw_ref[i, 0], hw_ref[i, 1]
     W = o_ref.shape[-1]
     last_stage = len(radii) - 1
-    for si, r in enumerate(radii):       # chain static; settings runtime
-        reach = sum(radii[si + 1:])
-        rows = stage_rows[si]
-        _form_bank(r, rows, src, bank_ref)
 
-        if si == last_stage:
-            def store(last, r0, c0, si=si):
-                for k in range(grid.num_outputs):
-                    o_ref[0, k, pl.ds(r0, last.shape[-2]),
-                          pl.ds(c0, last.shape[-1])] = _output(
-                        grid, (si, i), outsel_ref, last, k)
-        else:
-            def store(last, r0, c0, si=si, reach=reach):
-                # The forwarded channel is output k = out_ch of the stage,
-                # i.e. last-level row out_sel[k] (forward_stage_output).
-                k = _clamp(outch_ref[si, i], grid.num_outputs)
-                y = _output(grid, (si, i), outsel_ref, last, k)
-                shape = y.shape
-                grow = (t * tile_rows - reach + r0) + (
-                    jax.lax.broadcasted_iota(jnp.int32, shape, 0))
-                gcol = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-                valid = jnp.logical_and(
-                    jnp.logical_and(grow >= 0, grow < hw_ref[i, 0]),
-                    gcol < hw_ref[i, 1],
-                )
-                x_ref[pl.ds(r0, shape[0]), pl.ds(c0, shape[1])] = jnp.where(
-                    valid, y, jnp.zeros_like(y))
+    @pl.when(live)
+    def _():
+        src = slabs_ref.at[slot]         # haloed rows of the whole chain
+        for si, r in enumerate(radii):   # chain static; settings runtime
+            reach = sum(radii[si + 1:])
+            rows = stage_rows[si]
+            _form_bank(r, rows, src, bank_ref)
 
-        _datapath(grid, r, rows, W, (si, i),
-                  (tap_ref, op_ref, sel_ref, const_ref, bank_ref, chan_ref,
-                   lvl_ref), store)
-        src = x_ref
+            if si == last_stage:
+                def store(last, r0, c0, si=si):
+                    for k in range(grid.num_outputs):
+                        y = _output(grid, (si, i), outsel_ref, last, k)
+                        o_ref[0, k, pl.ds(r0, y.shape[-2]),
+                              pl.ds(c0, y.shape[-1])] = _in_extent(
+                            y, t * tile_rows + r0, c0, h, w)
+            else:
+                def store(last, r0, c0, si=si, reach=reach):
+                    # The forwarded channel is output k = out_ch of the
+                    # stage, i.e. last-level row out_sel[k]
+                    # (forward_stage_output).
+                    k = _clamp(outch_ref[si, i], grid.num_outputs)
+                    y = _output(grid, (si, i), outsel_ref, last, k)
+                    x_ref[pl.ds(r0, y.shape[0]), pl.ds(c0, y.shape[1])] = (
+                        _in_extent(y, t * tile_rows - reach + r0, c0, h, w))
+
+            _datapath(grid, r, rows, W, (si, i),
+                      (tap_ref, op_ref, sel_ref, const_ref, bank_ref,
+                       chan_ref, lvl_ref), store)
+            src = x_ref
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
 
 def vcgra_pipeline_batched(
@@ -702,7 +764,9 @@ def vcgra_pipeline_batched(
     int32 [S, N], the channel stage *i* feeds forward (the last stage's
     row is carried for shape uniformity but never read); ``hw``: int32
     [N, 2] true (rows, cols) of each app's frame inside the canvas;
-    ``images``: [N, H, W].  Returns [N, num_outputs, H*W] in grid dtype.
+    ``images``: [N, H, W].  Returns [N, num_outputs, H*W] in grid dtype,
+    zero outside each app's extent (a padded slot carries ``(0, 0)`` and
+    runs no datapath).
 
     The frame stack is zero-padded by the chain's TOTAL radius
     ``R = sum(radii)`` rows (plus the layout's sublane alignment, see

@@ -231,22 +231,43 @@ def shard_apps_rows(fn: Callable, mesh: Mesh, radius: int,
     each shard's ``band * W`` pixels are one contiguous block and the
     out-spec ``P(app, None, rows)`` reassembles frames with no data
     movement.
+
+    The executor sees band-local rows, so it runs on the whole haloed
+    band (extent None); the apps' extents ``hw`` (int32 ``[N, 2]`` or
+    None) then zero the kept rows outside each frame by their global row
+    index, as every executor's output contract asks.
     """
     rows = mesh.shape[row_axis]
     r = int(radius)
 
-    def banded(configs, ingests, slab):
+    def banded(configs, ingests, hw, slab):
         haloed = halo_exchange_rows(slab, r, rows, axis=row_axis)
-        ys = fn(configs, ingests, haloed)
+        ys = fn(configs, ingests, None, haloed)
         n, band, W = slab.shape
         ys = ys.reshape(n, -1, band + 2 * r, W)[:, :, r:r + band, :]
+        if hw is not None:
+            ys = jnp.where(_band_extent(hw, band, W, row_axis)[:, None], ys, 0)
         return ys.reshape(n, ys.shape[1], band * W)
 
     return _shard_map(
         banded, mesh=mesh,
-        in_specs=(P(app_axis), P(app_axis), P(app_axis, row_axis)),
+        in_specs=(P(app_axis), P(app_axis), P(app_axis),
+                  P(app_axis, row_axis)),
         out_specs=P(app_axis, None, row_axis),
     )
+
+
+def _band_extent(hw: jnp.ndarray, band: int, W: int,
+                 row_axis: str) -> jnp.ndarray:
+    """``[n, band, W]`` bool mask of each app's frame extent ``hw`` over
+    this shard's row band; a band row's global index is recovered from
+    ``axis_index(rows) * band``."""
+    row0 = jax.lax.axis_index(row_axis) * band
+    rows_in = ((row0 + jnp.arange(band, dtype=jnp.int32))[None, :, None]
+               < hw[:, 0][:, None, None])
+    cols_in = (jnp.arange(W, dtype=jnp.int32)[None, None, :]
+               < hw[:, 1][:, None, None])
+    return jnp.logical_and(rows_in, cols_in)
 
 
 def shard_pipeline_rows(stage_fn, mesh: Mesh, radii,
@@ -278,23 +299,14 @@ def shard_pipeline_rows(stage_fn, mesh: Mesh, radii,
 
     def banded(stage_settings, hw, slab):
         n, band, W = slab.shape
-        row0 = jax.lax.axis_index(row_axis) * band
-        rows_in = (
-            (row0 + jnp.arange(band, dtype=jnp.int32))[None, :, None]
-            < hw[:, 0][:, None, None]
-        )
-        cols_in = (
-            jnp.arange(W, dtype=jnp.int32)[None, None, :]
-            < hw[:, 1][:, None, None]
-        )
-        valid = jnp.logical_and(rows_in, cols_in)
+        valid = _band_extent(hw, band, W, row_axis)
         x = slab
         ys = None
         for si, r in enumerate(radii):
             r = int(r)
             haloed = halo_exchange_rows(x, r, rows, axis=row_axis)
             ys = stage_fn(r, stage_settings[si][0], stage_settings[si][1],
-                          haloed)
+                          None, haloed)
             ys = ys.reshape(n, -1, band + 2 * r, W)[:, :, r:r + band, :]
             if si < depth - 1:
                 out_ch = stage_settings[si][2]
@@ -302,6 +314,7 @@ def shard_pipeline_rows(stage_fn, mesh: Mesh, radii,
                     ys, out_ch.astype(jnp.int32)[:, None, None, None], axis=1
                 )[:, 0]
                 x = jnp.where(valid, y, 0)
+        ys = jnp.where(valid[:, None], ys, 0)
         return ys.reshape(n, ys.shape[1], band * W)
 
     return _shard_map(

@@ -196,6 +196,15 @@ class FleetStats:
     # bench asserts they actually happen under deadline pressure.
     partial_tile_dispatches: int = 0
     padded_app_slots: int = 0    # wasted N-axis slots from tile rounding
+    # Grid steps (app slot x row tile) of the fused and pipeline pallas
+    # megakernels, and the live ones: a step computes only where its
+    # output rows hold a pixel of its slot's frame (padded slots carry
+    # extent (0, 0)).  Counted on the host by the kernel's own predicate
+    # (``kernels.vcgra.grid_steps``), per dispatch also on its
+    # ``pixie.execute`` span (``steps=``, ``live=``).  XLA plans and mesh
+    # plans (kernels per shard or per stage) add none.
+    kernel_steps: int = 0
+    kernel_steps_live: int = 0
     map_calls: int = 0           # place/route runs (config-cache misses)
     config_cache_hits: int = 0
     overlay_builds: int = 0      # batched executables built (per OverlayPlan)
@@ -228,6 +237,17 @@ class FleetStats:
 
     def as_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
+
+
+def _extents(items: Sequence[Tuple[int, "_Prepared"]],
+             n_tile: int) -> np.ndarray:
+    """int32 ``[n_tile, 2]``: each item's true frame ``(rows, cols)`` in
+    its app slot, ``(0, 0)`` for the padded slots.  A numpy operand: the
+    dispatch ships it with its own arguments, no eager device op."""
+    hw = np.zeros((n_tile, 2), np.int32)
+    for i, (_, p) in enumerate(items):
+        hw[i] = p.hw
+    return hw
 
 
 @dataclasses.dataclass
@@ -924,22 +944,27 @@ class PixieFleet:
             # split and the executable's own row padding is a no-op.
             Hb = row_band(Hb, plan.mesh.rows, radius) * plan.mesh.rows
         configs = [p.cfg for _, p in items]
-        # Tile padding on the app axis: replay config[0] on a zero frame.
+        # Tile padding on the app axis: replay config[0] on a zero frame
+        # of extent (0, 0), which the megakernel does not compute.
         configs += [configs[0]] * (n_tile - n)
         self.stats.padded_app_slots += n_tile - n
         self.stats.partial_tile_dispatches += 1 if n < n_tile else 0
 
         with TraceAnnotation("pixie.bank"):
             stacked, ingests = self._stacked_bank(grid, configs, fused=True)
+            hw = _extents(items, n_tile)
         frames = self._ship_frames(fn.mesh, n_tile, Hb, Wb, grid.dtype, items)
         # The canvas embed + bank build + ship above are host-side pack
         # work; the overlay execution below is not.
         self.timings["pack_s"] += time.perf_counter() - t0
         self._pre_dispatch(plan, items)
-        with TraceAnnotation("pixie.execute"):
-            ys = self._execute(fn, stacked, ingests, frames)
+        steps, live = self._kernel_steps(fn, radius, hw, Hb, Wb)
+        with TraceAnnotation("pixie.execute", steps=steps, live=live):
+            ys = self._execute(fn, stacked, ingests, hw, frames)
         ys = self._corrupt_outputs(plan, items, ys)
         self.stats.output_devices = len(ys.sharding.device_set)
+        self.stats.kernel_steps += steps
+        self.stats.kernel_steps_live += live
         self.stats.dispatches += 1
         self.stats.fused_dispatches += 1
         self.stats.stamp_dispatch(fn.plan, f"n{n_tile}x{Hb}x{Wb}")
@@ -961,8 +986,8 @@ class PixieFleet:
         stage through the same bank cache single-stage dispatches use) and
         the per-app true frame extents ``hw`` that executors use to
         re-mask intermediates.  Padded app slots replay item 0's chain on
-        a zero frame and are sliced off -- outputs are bitwise identical
-        to per-stage sequential flushes.
+        a zero frame of extent (0, 0) and are sliced off -- outputs are
+        bitwise identical to per-stage sequential flushes.
 
         ``plan`` arrives pre-built (the app-tile-padded spec tuple IS a
         plan axis), normally the primary from :meth:`_primary_plan`, or a
@@ -992,23 +1017,38 @@ class PixieFleet:
                 )
                 stage_settings.append((stacked, ingests, out_ch))
             stage_settings = tuple(stage_settings)
-            hw = np.full((n_tile, 2), (Hb, Wb), np.int32)
-            for i, (_, p) in enumerate(items):
-                hw[i] = p.hw
-            hw = jnp.asarray(hw)
+            hw = _extents(items, n_tile)
         frames = self._ship_frames(fn.mesh, n_tile, Hb, Wb, grid.dtype, items)
         self.timings["pack_s"] += time.perf_counter() - t0
         self._pre_dispatch(plan, items)
-        with TraceAnnotation("pixie.execute"):
+        steps, live = self._kernel_steps(fn, sum(radii), hw, Hb, Wb)
+        with TraceAnnotation("pixie.execute", steps=steps, live=live):
             ys = self._execute(fn, stage_settings, hw, frames)
         ys = self._corrupt_outputs(plan, items, ys)
         self.stats.output_devices = len(ys.sharding.device_set)
+        self.stats.kernel_steps += steps
+        self.stats.kernel_steps_live += live
         self.stats.dispatches += 1
         self.stats.fused_dispatches += 1
         self.stats.pipeline_dispatches += 1
         self.stats.stamp_dispatch(fn.plan, f"n{n_tile}x{Hb}x{Wb}")
         self.stats.executed += n
         self._unpack_frames(ys, items, Hb, Wb, out)
+
+    @staticmethod
+    def _kernel_steps(fn: OverlayExecutable, halo: int, hw: np.ndarray,
+                      Hb: int, Wb: int) -> Tuple[int, int]:
+        """``(steps, live)`` of a fused or chained dispatch's pallas
+        megakernel over its ``[n_tile, Hb, Wb]`` canvas (``halo``: the
+        plan's radius, a chain's total radius); ``(0, 0)`` where no one
+        megakernel call covers the canvas (XLA plans, and mesh plans,
+        whose kernels run per shard or per stage)."""
+        plan = fn.plan
+        if plan.backend != "pallas" or fn.mesh is not None:
+            return 0, 0
+        from repro.kernels.vcgra import grid_steps
+
+        return grid_steps(plan.grid, halo, plan.tile_rows, hw, Hb, Wb)
 
     def _unpack_frames(self, ys, items: List[Tuple[int, _Prepared]],
                        Hb: int, Wb: int, out: Dict[int, Any]) -> None:

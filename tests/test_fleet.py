@@ -367,6 +367,28 @@ def test_frontend_flush_latency_accounting(rng):
 # -- async (double-buffered) ingest -------------------------------------------
 
 
+def test_async_pallas_partial_tile_matches_numpy(rng):
+    """A partial app tile of frames in 1080p's proportions (18 x 32 on a
+    32 x 32 canvas in row tiles of 8): the megakernel skips each frame's
+    last tile and the padded slot, and every output still equals the
+    numpy reference; the fleet counts 9 live of 16 steps."""
+    kernels = {"sobel_x": apps.SOBEL_X, "sobel_y": apps.SOBEL_Y,
+               "laplace": apps.LAPLACE}
+    images = [rng.integers(0, 256, (18, 32)).astype(np.int32)
+              for _ in kernels]
+    fleet = PixieFleet(default_grid=sobel_grid(), backend="pallas",
+                       ingest="async", batch_tile=4, tile_rows=8)
+    got = fleet.run_many([FleetRequest(app=n, image=im)
+                          for n, im in zip(kernels, images)])
+    for k, im, y in zip(kernels.values(), images, got):
+        np.testing.assert_array_equal(np.asarray(y),
+                                      apps.conv2d_reference(im, k))
+    assert fleet.stats.padded_app_slots == 1
+    assert (fleet.stats.kernel_steps, fleet.stats.kernel_steps_live) == (
+        16, 9)
+    assert all("|n4x32x32" in k for k in fleet.stats.dispatch_plans)
+
+
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
 def test_async_ingest_bitwise_mixed_flushes(backend, rng):
     """ingest="async" == ingest="sync", bitwise, under repeated mixed

@@ -120,6 +120,7 @@ def test_batched_fused_matches_unfused_ragged(backend, rng):
     ys = fn(
         VCGRAConfig.stack(configs),
         IngestPlan.stack([c.ingest for c in configs], GRID_ALL.dtype),
+        np.asarray(hws, np.int32),
         jnp.asarray(canvas),
     )
     for i, (cfg, img) in enumerate(zip(configs, images)):

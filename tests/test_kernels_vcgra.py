@@ -1,7 +1,8 @@
 """Kernel tests: VCGRA Pallas executor (specialized + conventional) vs the
 pure-jnp oracle, swept over applications, shapes and dtypes -- plus the
-batched fused-ingest megakernel (N tenants, raw frames, one pallas_call)
-vs the batched interpreter oracle."""
+batched fused-ingest and chain megakernels (N tenants, raw frames, one
+pallas_call) vs the batched interpreter oracle, at frame extents that
+leave whole row tiles and app slots empty."""
 
 import functools
 
@@ -12,7 +13,7 @@ import pytest
 
 from conftest import shared_app_grid
 
-from repro.core import for_dfg, map_app, sobel_grid
+from repro.core import OverlayPlan, compile_plan, for_dfg, map_app, sobel_grid
 from repro.core import applications as apps
 from repro.core.bitstream import VCGRAConfig
 from repro.core.grid import rectangular
@@ -24,8 +25,10 @@ from repro.core.interpreter import (
     pad_channels,
 )
 from repro.core.ops import Op, apply_generic
+from repro.core.plan import PipelineSpec
 from repro.kernels.vcgra import (
     default_interpret,
+    grid_steps,
     make_batched_fused_pallas_fn,
     make_batched_pallas_fn,
     pack_settings_batched,
@@ -34,6 +37,8 @@ from repro.kernels.vcgra import (
     vcgra_ref,
 )
 from repro.kernels.vcgra.vcgra_kernel import _pack_settings, vcgra_batched
+
+from test_pipeline import GRID as CHAIN_GRID, _stage_settings, chain_configs
 
 
 def _setup(app_name, data_bits=32, float_pe=False, shape="exact"):
@@ -204,11 +209,12 @@ def test_megakernel_fused_batched_matches_interpreter_all_apps(rng):
 
     stacked = VCGRAConfig.stack(configs)
     ingests = IngestPlan.stack([c.ingest for c in configs], MEGA_GRID.dtype)
+    hw = np.asarray([i.shape for i in images], np.int32)
     ref = batched_fused_overlay_step(
-        MEGA_GRID, 1, stacked, ingests, jnp.asarray(canvas)
+        MEGA_GRID, 1, stacked, ingests, jnp.asarray(canvas), hw
     )
     got = make_batched_fused_pallas_fn(MEGA_GRID, radius=1)(
-        stacked, ingests, jnp.asarray(canvas)
+        stacked, ingests, hw, jnp.asarray(canvas)
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -238,31 +244,33 @@ def test_megakernel_casts_frames_to_grid_dtype_like_oracle(rng):
     stacked = VCGRAConfig.stack(configs)
     ingests = IngestPlan.stack([c.ingest for c in configs], grid.dtype)
     ref = batched_fused_overlay_step(grid, 1, stacked, ingests, jnp.asarray(imgs))
-    got = make_batched_fused_pallas_fn(grid, radius=1)(stacked, ingests,
+    got = make_batched_fused_pallas_fn(grid, radius=1)(stacked, ingests, None,
                                                        jnp.asarray(imgs))
     assert got.dtype == ref.dtype == grid.dtype
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 def test_megakernel_settings_are_runtime_data(rng):
-    """Compile-once: swapping which app runs in which slot must reuse the
-    jitted megakernel executable (settings are SMEM operands, not trace
-    constants)."""
+    """Compile-once: swapping which app runs in which slot, and the frame
+    extents the slots hold, must reuse the jitted megakernel executable
+    (settings and extents are SMEM operands, not trace constants)."""
     grid = sobel_grid()
     img = rng.integers(0, 256, (2, 8, 8)).astype(np.int32)
     fn = make_batched_fused_pallas_fn(grid, radius=1)
     pair_a = [map_app(apps.ALL_APPS[n](), grid) for n in ["sobel_x", "laplace"]]
     pair_b = [map_app(apps.ALL_APPS[n](), grid) for n in ["sobel_y", "identity"]]
-    for pair in (pair_a, pair_b):
+    hw_a = np.asarray([[8, 8], [0, 0]], np.int32)
+    hw_b = np.asarray([[3, 5], [8, 6]], np.int32)
+    for pair, hw in ((pair_a, hw_a), (pair_b, hw_b)):
         got = fn(
             VCGRAConfig.stack(pair),
             IngestPlan.stack([c.ingest for c in pair], grid.dtype),
-            jnp.asarray(img),
+            hw, jnp.asarray(img),
         )
         ref = batched_fused_overlay_step(
             grid, 1, VCGRAConfig.stack(pair),
             IngestPlan.stack([c.ingest for c in pair], grid.dtype),
-            jnp.asarray(img),
+            jnp.asarray(img), hw,
         )
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     # The compile-once assert is the point of this test; if jax ever drops
@@ -272,3 +280,67 @@ def test_megakernel_settings_are_runtime_data(rng):
     if not callable(sizer):
         pytest.skip("this jax version has no jit _cache_size introspection")
     assert sizer() == 1
+
+
+# -- extent-bounded grid steps -------------------------------------------------
+#
+# A step (app slot, row tile) runs the datapath only where its output rows
+# hold a pixel of the slot's frame; every other output is zero.  The canvas
+# has three row tiles of EXT_TILE rows and lane-padded columns; the frame
+# heights sweep the tile boundaries.
+
+EXT_H, EXT_W, EXT_TILE = 21, 140, 8
+EXT_HEIGHTS = [0, 1, EXT_TILE - 1, EXT_TILE, EXT_TILE + 1, EXT_H]
+
+
+@functools.lru_cache(maxsize=None)
+def _extent_case(kind):
+    """``(pallas, xla, settings, halo)`` of one megakernel kind over three
+    app slots, each executable compiled once: extents are runtime data."""
+    if kind == "fused":
+        grid = sobel_grid()
+        configs = [map_app(apps.ALL_APPS[n](), grid)
+                   for n in ["sobel_x", "threshold", "laplace"]]
+        settings = (VCGRAConfig.stack(configs),
+                    IngestPlan.stack([c.ingest for c in configs], grid.dtype))
+        plans = [OverlayPlan(grid=grid, batched=True, fused=True,
+                             backend=b, tile_rows=EXT_TILE)
+                 for b in ("pallas", "xla")]
+        halo = 1
+    else:
+        # The last stage reads past the extent's edge, so its outputs there
+        # are not zero unless the kernel zeroes them.
+        spec = PipelineSpec.chain(
+            chain_configs(names=["gauss3", "threshold", "sobel_x"]))
+        settings = (_stage_settings([spec] * 3),)
+        plans = [OverlayPlan(grid=CHAIN_GRID, batched=True,
+                             pipeline=(spec,) * 3, backend=b,
+                             tile_rows=EXT_TILE)
+                 for b in ("pallas", "xla")]
+        halo = spec.total_radius
+    pallas, xla = (compile_plan(p) for p in plans)
+    return pallas, xla, settings, halo
+
+
+@pytest.mark.parametrize("h", EXT_HEIGHTS)
+@pytest.mark.parametrize("kind", ["fused", "pipeline"])
+def test_megakernel_zero_outside_extent_matches_xla(kind, h, rng):
+    """Both megakernels at a frame height on each side of a tile boundary,
+    a width short of the canvas, an empty (0, 0) slot and a full one: the
+    whole canvas is bitwise equal to the XLA twin and zero outside every
+    extent, and the host's step count is the kernel's predicate."""
+    pallas, xla, settings, halo = _extent_case(kind)
+    hw = np.asarray([[h, 100], [0, 0], [EXT_H, EXT_W]], np.int32)
+    canvas = np.zeros((3, EXT_H, EXT_W), np.int32)
+    for i, (fh, fw) in enumerate(hw):
+        canvas[i, :fh, :fw] = rng.integers(0, 256, (fh, fw))
+    args = (*settings, hw, jnp.asarray(canvas))
+    got = np.asarray(pallas(*args))
+    np.testing.assert_array_equal(got, np.asarray(xla(*args)))
+    for i, (fh, fw) in enumerate(hw):
+        frame = got[i].reshape(-1, EXT_H, EXT_W)
+        assert not frame[:, fh:, :].any() and not frame[:, :, fw:].any()
+    assert got[2].any()
+    grid = pallas.plan.grid
+    live = min(-(-h // EXT_TILE), 3) + 3
+    assert grid_steps(grid, halo, EXT_TILE, hw, EXT_H, EXT_W) == (9, live)

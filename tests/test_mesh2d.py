@@ -193,7 +193,8 @@ def _plan_outputs(workload, spec, backend, tile_rows=None):
     stacked, ingests, canvas = workload
     plan = OverlayPlan(grid=GRID, batched=True, fused=True, radius=1,
                        backend=backend, mesh=spec, tile_rows=tile_rows)
-    return np.asarray(compile_plan(plan)(stacked, ingests, canvas))
+    hw = np.asarray(HWS, np.int32)
+    return np.asarray(compile_plan(plan)(stacked, ingests, hw, canvas))
 
 
 @needs_four_devices

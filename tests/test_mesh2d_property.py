@@ -69,5 +69,6 @@ def test_property_2d_parity(case):
     for spec in (MeshSpec(), MeshSpec(app=app, rows=rows)):
         plan = OverlayPlan(grid=GRID, batched=True, fused=True,
                            radius=radius, mesh=spec)
-        outs.append(np.asarray(compile_plan(plan)(stacked, ingests, canvas)))
+        outs.append(np.asarray(compile_plan(plan)(stacked, ingests, None,
+                                                  canvas)))
     np.testing.assert_array_equal(outs[0], outs[1])

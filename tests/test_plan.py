@@ -174,7 +174,7 @@ def test_deprecated_shims_warn_and_match_compile_plan_bitwise(rng):
          (cfg.to_jax(), cfg.ingest.to_jax(GRID.dtype), jnp.asarray(img))),
         (lambda: interpreter.make_batched_fused_overlay_fn(GRID),
          OverlayPlan(grid=GRID, batched=True, fused=True),
-         (stacked, ingests, imgs)),
+         (stacked, ingests, None, imgs)),
     ]
     for make, plan, operands in cases:
         with pytest.warns(DeprecationWarning, match="compile_plan"):
@@ -280,9 +280,10 @@ def test_sharded_compile_plan_bitwise_ragged_nonsquare(backend, rng):
     two = compile_plan(OverlayPlan(grid=GRID, batched=True, fused=True,
                                    backend=backend, devices=2))
     assert two.mesh is not None and two.mesh.shape["app"] == 2
+    hw = np.asarray(hws, np.int32)
     np.testing.assert_array_equal(
-        np.asarray(one(stacked, ingests, canvas)),
-        np.asarray(two(stacked, ingests, canvas)),
+        np.asarray(one(stacked, ingests, hw, canvas)),
+        np.asarray(two(stacked, ingests, hw, canvas)),
     )
 
 

@@ -139,13 +139,13 @@ def test_tiled_matches_untiled_oracle_bitwise(backend, tile_rows, rng):
     names = ["sobel_x", "sharpen", "identity", "laplace"]
     hws = [(13, 11), (9, 4), (7, 7), (3, 10)]
     stacked, ingests, canvas = _stacked_workload(rng, names, hws)
-    oracle = np.asarray(
-        interpreter.batched_fused_overlay_step(GRID, 1, stacked, ingests, canvas)
-    )
+    hw = np.asarray(hws, np.int32)
+    oracle = np.asarray(interpreter.batched_fused_overlay_step(
+        GRID, 1, stacked, ingests, canvas, hw))
     exe = compile_plan(OverlayPlan(grid=GRID, batched=True, fused=True,
                                    backend=backend, tile_rows=tile_rows))
     np.testing.assert_array_equal(
-        np.asarray(exe(stacked, ingests, canvas)), oracle
+        np.asarray(exe(stacked, ingests, hw, canvas)), oracle
     )
 
 
@@ -213,7 +213,7 @@ def assert_tiled_equals_untiled(H, W, radius, tile_rows, n, seed, backend):
             GRID, radius, tile_rows, stacked, ingests, images)
     else:
         tiled = _batched_fused_pallas_fn(
-            GRID, radius, tile_rows=tile_rows)(stacked, ingests, images)
+            GRID, radius, tile_rows=tile_rows)(stacked, ingests, None, images)
     np.testing.assert_array_equal(np.asarray(tiled), oracle)
 
 

@@ -94,6 +94,7 @@ def test_fused_megakernel_compiles(one_chip, side):
     fn = _batched_fused_pallas_fn(GRID, 1, interpret=False,
                                   tile_rows=TILE_AUTO)
     _compile(fn, _configs(one_chip), _ingests(one_chip),
+             _spec(one_chip, (N, 2)),
              _spec(one_chip, (N, side, side), GRID.dtype))
 
 
@@ -118,7 +119,7 @@ def test_small_canvas_fused_megakernel_compiles(one_chip, hw, tile_rows):
     fn = _batched_fused_pallas_fn(GRID, 1, interpret=False,
                                   tile_rows=tile_rows)
     _compile(fn, _configs(one_chip, n=2), _ingests(one_chip, n=2),
-             _spec(one_chip, (2, *hw), GRID.dtype))
+             _spec(one_chip, (2, 2)), _spec(one_chip, (2, *hw), GRID.dtype))
 
 
 def test_float_grid_fused_megakernel_compiles(one_chip):
@@ -127,7 +128,7 @@ def test_float_grid_fused_megakernel_compiles(one_chip):
     grid = sobel_grid(float_pe=True)
     fn = _batched_fused_pallas_fn(grid, 1, interpret=False, tile_rows=8)
     _compile(fn, _configs(one_chip, grid, n=2),
-             _ingests(one_chip, grid, n=2),
+             _ingests(one_chip, grid, n=2), _spec(one_chip, (2, 2)),
              _spec(one_chip, (2, 64, 128), grid.dtype))
 
 
@@ -163,7 +164,8 @@ def test_served_plans_name_their_megakernels(one_chip, monkeypatch, kind):
         plan = OverlayPlan(grid=GRID, batched=True, fused=True, radius=1,
                            backend="pallas", tile_rows=TILE_AUTO,
                            ingest="async")
-        args = (_configs(one_chip, n=n), _ingests(one_chip, n=n), frames)
+        args = (_configs(one_chip, n=n), _ingests(one_chip, n=n),
+                _spec(one_chip, (n, 2)), frames)
     else:
         spec = PipelineSpec.chain([
             map_app(applications.ALL_APPS[a](), GRID)
@@ -191,7 +193,7 @@ def _mosaic_payload(sharding, source_regex):
         fn = _batched_fused_pallas_fn(GRID, 1, interpret=False,
                                       tile_rows=TILE_AUTO)
         text = jax.jit(fn).lower(
-            _configs(sharding), _ingests(sharding),
+            _configs(sharding), _ingests(sharding), _spec(sharding, (N, 2)),
             _spec(sharding, (N, 256, 256), GRID.dtype)).as_text()
     finally:
         jax.config.update("jax_hlo_source_file_canonicalization_regex", was)

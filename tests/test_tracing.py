@@ -31,8 +31,9 @@ DISPATCH = ["pixie.bank", "pixie.canvas_wait", "pixie.embed", "pixie.ship",
 
 def traced(logdir, serve):
     """Run ``serve()`` under the profiler; returns its result and the
-    ``pixie.*`` spans of the trace as ``(thread, name, start, end)``,
-    ``thread`` being the index of the host line that holds the span."""
+    ``pixie.*`` spans of the trace as ``(thread, name, start, end,
+    args)``, ``thread`` being the index of the host line that holds the
+    span and ``args`` the dict of the arguments it was annotated with."""
     with jax.profiler.trace(str(logdir)):
         result = serve()
     path, = glob.glob(os.path.join(str(logdir), "plugins", "profile", "*",
@@ -44,7 +45,8 @@ def traced(logdir, serve):
         for index, line in enumerate(plane.lines):
             for ev in line.events:
                 if ev.name.startswith("pixie."):
-                    spans.append((index, ev.name, ev.start_ns, ev.end_ns))
+                    spans.append((index, ev.name, ev.start_ns, ev.end_ns,
+                                  dict(ev.stats)))
     spans.sort(key=lambda s: (s[2], -s[3]))
     return result, spans
 
@@ -159,6 +161,33 @@ def test_channel_dispatch_executes_and_unpacks(tmp_path, rng):
     assert names(spans) == ["pixie.intake", "pixie.bank", "pixie.execute",
                             "pixie.unpack"]
     assert_in_order(spans)
+
+
+def test_execute_spans_carry_the_kernel_steps(tmp_path, rng):
+    """A pallas dispatch's execute span carries its megakernel's grid
+    steps and the live ones, and the fleet's counters add up the same:
+    frames in 1080p's proportions (18 x 32) on 32 x 32 canvases in row
+    tiles of 8, so a frame's fourth tile and the padded app slots are
+    dead.  The fused dispatch holds 3 frames in 4 slots (9 live of 16
+    steps), the chain 1 (3 live of 16)."""
+    fleet = PixieFleet(default_grid=sobel_grid(), batch_tile=4,
+                       backend="pallas", ingest="async", tile_rows=8)
+    imgs = frames(rng, 3, hw=(18, 32))
+    reqs = [FleetRequest(app=a, image=im)
+            for a, im in zip(["sobel_x", "sobel_y", "laplace"], imgs)]
+    reqs.append(FleetRequest(pipeline=["sobel_x", "threshold"],
+                             image=imgs[0]))
+    fleet.run_many(reqs)   # compile outside the trace
+    assert (fleet.stats.kernel_steps, fleet.stats.kernel_steps_live) == (
+        32, 12)
+
+    _, spans = traced(tmp_path, lambda: [np.asarray(y)
+                                         for y in fleet.run_many(reqs)])
+    executes = [s[4] for s in spans if s[1] == "pixie.execute"]
+    assert sorted((a["steps"], a["live"]) for a in executes) == [
+        (16, 3), (16, 9)]
+    assert (fleet.stats.kernel_steps, fleet.stats.kernel_steps_live) == (
+        64, 24)
 
 
 def serve_stream(images, apps):
